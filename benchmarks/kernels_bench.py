@@ -37,7 +37,7 @@ def bench_lut16():
     from repro.kernels.ops import pack_codes
     packed = jnp.asarray(pack_codes(np.asarray(codes)))
     s_pk, _ = timeit(lambda: lut16_adc(
-        packed, lut, bq=8, bn=512, bk=16, packed=True).block_until_ready())
+        packed, lut, bq=8, bn=512, packed=True).block_until_ready())
     # structural: bytes per datapoint scanned
     pq_bytes = k                      # uint8 per subspace
     packed_bytes = packed.shape[1]    # two 4-bit codes per byte
@@ -53,18 +53,20 @@ def bench_lut16():
          f"traffic_reduction={dense_bytes / packed_bytes:.0f}x")
 
 
-def fused_vmem_bytes(bq: int, bn: int, bk: int, *, l: int = 16,
+def fused_vmem_bytes(bq: int, bn: int, k: int, *, l: int = 16,
                      packed: bool = False, cbuf: int = 128) -> int:
     """Resident VMEM estimate for one fused scan-and-select grid step
-    (DESIGN.md §2.5's budget table): code block + LUT block + accumulator
-    scratch + candidate buffers, times 2 for the double-buffered input
-    stream Pallas pipelines automatically."""
-    lut_bk = 2 * bk if packed else bk
-    codes_blk = bn * bk                       # uint8
-    lut_blk = bq * lut_bk * l * 4             # f32
-    acc = bq * bn * 4                         # f32 scratch
+    (DESIGN.md §2.5's budget table): code block (the whole stored K axis)
+    + chunked LUT block, times 2 for the double-buffered input stream
+    Pallas pipelines automatically, + one chunk's (128, bn) one-hot + the
+    score block + candidate buffers."""
+    kc = -(-k // 2) if packed else k
+    codes_blk = bn * kc                       # uint8
+    lut_blk = bq * -(-k // (128 // l)) * 128 * 4   # f32, chunk-padded
+    onehot = 128 * bn * 4                     # f32, one chunk
+    acc = bq * bn * 4                         # f32 score block
     buf = bq * cbuf * (4 + 4)                 # f32 scores + i32 ids
-    return 2 * (codes_blk + lut_blk) + acc + buf
+    return 2 * (codes_blk + lut_blk) + onehot + acc + buf
 
 
 VMEM_BUDGET = 16 * 2 ** 20                    # v5e per-core VMEM
@@ -73,7 +75,7 @@ VMEM_BUDGET = 16 * 2 ** 20                    # v5e per-core VMEM
 def autotune_fused_blocks(*, n: int = 8192, k: int = 32, l: int = 16,
                           q: int = 8, topk: int = 128,
                           packed: bool = False) -> dict:
-    """Sweep the fused kernel's (bq, bn, bk) grid under the VMEM budget and
+    """Sweep the fused kernel's (bq, bn) grid under the VMEM budget and
     time each candidate on a small workload.  Returns the swept candidates
     (with VMEM estimates), the fastest config, and the budget — recorded in
     BENCH_engine.json so the shipped defaults are an audited choice, not a
@@ -95,21 +97,19 @@ def autotune_fused_blocks(*, n: int = 8192, k: int = 32, l: int = 16,
     best = None
     for bq in (8,):
         for bn in (128, 256, 512, 1024):
-            for bk in (8, 16, 32):
-                vmem = fused_vmem_bytes(bq, bn, bk, l=l, packed=packed,
-                                        cbuf=cbuf)
-                entry = {"bq": bq, "bn": bn, "bk": bk, "vmem_bytes": vmem,
-                         "fits": vmem <= VMEM_BUDGET}
-                if entry["fits"]:
-                    fn = lambda: lut16_adc_topk(
-                        codes, lut, topk, bq=bq, bn=bn, bk=bk,
-                        packed=packed)[0].block_until_ready()
-                    fn()                      # warmup/compile
-                    secs, _ = timeit(fn, repeat=3)
-                    entry["us"] = secs * 1e6
-                    if best is None or entry["us"] < best["us"]:
-                        best = entry
-                candidates.append(entry)
+            vmem = fused_vmem_bytes(bq, bn, k, l=l, packed=packed, cbuf=cbuf)
+            entry = {"bq": bq, "bn": bn, "vmem_bytes": vmem,
+                     "fits": vmem <= VMEM_BUDGET}
+            if entry["fits"]:
+                fn = lambda: lut16_adc_topk(
+                    codes, lut, topk, bq=bq, bn=bn,
+                    packed=packed)[0].block_until_ready()
+                fn()                          # warmup/compile
+                secs, _ = timeit(fn, repeat=3)
+                entry["us"] = secs * 1e6
+                if best is None or entry["us"] < best["us"]:
+                    best = entry
+            candidates.append(entry)
     return {"workload": {"n": n, "k": k, "l": l, "q": q, "topk": topk,
                          "packed": packed},
             "budget_bytes": VMEM_BUDGET, "candidates": candidates,

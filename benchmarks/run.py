@@ -14,6 +14,8 @@ def main() -> None:
                     help="comma list: table2,table3,fig4,kernels,engine,"
                          "serve,persist,cluster,roofline")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     names = (args.only.split(",") if args.only
              else ["fig4", "kernels", "engine", "serve", "persist",
                    "cluster", "table2", "table3", "roofline"])

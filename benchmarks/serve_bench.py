@@ -29,7 +29,7 @@ Emits CSV rows like the other benchmark modules AND writes
                              tracing; CI floors the ratios (>= 0.97 / 0.90)
     profile                  per-pass device-time attribution (§9.3):
                              {pass1_s, full_s, pass23_s, pass1_fraction,
-                             iters, backend, profiler_available}
+                             iters, backend}
     smoke                    true when run with --smoke (CI scale)
 
 ``--stream`` instead runs the streaming-mutation workload (DESIGN.md §6)
@@ -87,8 +87,7 @@ from repro.core.hybrid import HybridIndex, HybridIndexParams
 from repro.core.pq import pack_codes
 from repro.core.sparse_index import sparse_queries_to_padded
 from repro.data import make_hybrid_dataset
-from repro.obs import (Observability, device_trace, pass_breakdown,
-                       profiler_available)
+from repro.obs import Observability, device_trace, pass_breakdown
 from repro.serve import QueryService
 
 from .common import emit, timeit
@@ -262,7 +261,6 @@ def main(smoke: bool = False, profile_dir: str | None = None):
                               jnp.asarray(q_vals), jnp.asarray(q_dense),
                               h=H, alpha=ALPHA, beta=BETA,
                               iters=2 if smoke else 3)
-    prof["profiler_available"] = profiler_available()
     emit("serve_pass_breakdown", prof["full_s"] * 1e6,
          f"pass1_fraction={prof['pass1_fraction']:.3f};"
          f"pass1_us={prof['pass1_s'] * 1e6:.0f}")

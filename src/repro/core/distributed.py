@@ -27,9 +27,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-
-from repro import compat
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import engine as eng
 from . import residual as res
@@ -285,7 +283,7 @@ def make_sharded_search_fn(mesh: Mesh, *, k: int, axis: str = "data",
     backend = eng.Backend.from_name(adc)
     spec_rows = P(axis)        # row-sharded index structures
     spec_rep = P()             # replicated queries
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         functools.partial(_pass1_local, k=k, axis=axis, backend=backend),
         mesh=mesh,
         in_specs=(spec_rows, spec_rep, P(axis, None), P(axis, None),
@@ -366,7 +364,7 @@ def make_sharded_search3_fn(mesh: Mesh, *, h: int, alpha: int = 20,
     backend = eng.Backend.from_name(adc)
     rows = P(axis)
     rep = P()
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         functools.partial(_search3_local, h=h, alpha=alpha, beta=beta,
                           axis=axis, backend=backend),
         mesh=mesh,
@@ -386,14 +384,23 @@ def sharded_three_pass_topk(mesh: Mesh, codes, lut, inv_rows, inv_vals,
                             q_dims, q_vals, q_dense, q_cols, *, h: int,
                             alpha: int = 20, beta: int = 5,
                             axis: str = "data", adc: str = "gather"):
-    """Convenience wrapper: derives row_offset from equal row sharding and
-    runs the full three-pass fan-out search."""
+    """Convenience wrapper: places the inputs on the mesh, derives
+    row_offset from equal row sharding, and runs the full three-pass
+    fan-out search.
+
+    Row-sharded inputs go through ``NamedSharding(mesh, P(axis))``, so from
+    host (numpy) arrays every device receives only its own rows and no
+    device ever holds the whole index; the rest is replicated."""
     num_shards = mesh.shape[axis]
     n = codes.shape[0]
     assert n % num_shards == 0
-    row_offset = jnp.arange(num_shards, dtype=jnp.int32) * (n // num_shards)
+    row_offset = np.arange(num_shards, dtype=np.int32) * (n // num_shards)
+    rows, rep = NamedSharding(mesh, P(axis)), NamedSharding(mesh, P())
+    placed = [jax.device_put(x, s) for x, s in (
+        (codes, rows), (lut, rep), (inv_rows, rows), (inv_vals, rows),
+        (res_q, rows), (res_scale, rep), (res_zero, rep),
+        (sres_cols, rows), (sres_vals, rows), (q_dims, rep), (q_vals, rep),
+        (q_dense, rep), (q_cols, rep), (row_offset, rows))]
     fn = make_sharded_search3_fn(mesh, h=h, alpha=alpha, beta=beta, axis=axis,
                                  adc=adc)
-    return fn(codes, lut, inv_rows, inv_vals, res_q, res_scale, res_zero,
-              sres_cols, sres_vals, q_dims, q_vals, q_dense, q_cols,
-              row_offset)
+    return fn(*placed)

@@ -60,10 +60,12 @@ def _split_subspaces(x: jax.Array, k: int) -> jax.Array:
     return x.reshape(n, k, d // k)
 
 
-@partial(jax.jit, static_argnums=(1, 2, 3))
-def _kmeans_one_subspace(x: jax.Array, l: int, iters: int, seed: int) -> jax.Array:
+@partial(jax.jit, static_argnums=(1, 2))
+def _kmeans_one_subspace(x: jax.Array, l: int, iters: int,
+                         seed: jax.Array) -> jax.Array:
     """Lloyd's k-means on (N, p) -> (l, p) centers.  kmeans++-lite init:
-    random distinct points, deterministic under `seed`."""
+    random distinct points, deterministic under `seed` — a traced int32, so
+    every subspace of one build shares one compiled program."""
     n, p = x.shape
     key = jax.random.PRNGKey(seed)
     idx = jax.random.choice(key, n, shape=(l,), replace=False)
@@ -97,35 +99,50 @@ def train_codebooks(x_dense: jax.Array, num_subspaces: int, num_codes: int = 16,
         sel = jax.random.choice(jax.random.PRNGKey(seed + 101), x.shape[0],
                                 shape=(sample,), replace=False)
         x = x[sel]
-    subs = _split_subspaces(x, num_subspaces)                     # (N, K, p)
+    d = x.shape[1]
+    assert d % num_subspaces == 0, f"d={d} not divisible by K={num_subspaces}"
+    p = d // num_subspaces
     centers = []
     for k in range(num_subspaces):
-        centers.append(_kmeans_one_subspace(subs[:, k, :], num_codes, iters, seed + k))
+        # column slices of x, not an (N, K, p) split: its minor axis of p
+        # would be padded to 128 lanes on the TPU
+        centers.append(_kmeans_one_subspace(x[:, k * p:(k + 1) * p],
+                                            num_codes, iters,
+                                            jnp.int32(seed + k)))
     return PQCodebooks(centers=jnp.stack(centers))                # (K, l, p)
 
 
 @jax.jit
 def pq_encode(x_dense: jax.Array, codebooks: PQCodebooks) -> jax.Array:
-    """phi_PQ: (N, d) -> (N, K) uint8 codes (argmin L2 per subspace)."""
+    """phi_PQ: (N, d) -> (N, K) uint8 codes (argmin L2 per subspace).
+
+    Rows are encoded in blocks of 16k, so the (rows, K, l) distance
+    intermediate stays ~100 MiB at K=100 however large N is."""
     c = codebooks.centers                                         # (K, l, p)
     subs = _split_subspaces(jnp.asarray(x_dense, jnp.float32), c.shape[0])
-    # (N, K, l) squared distance; x-term constant wrt argmin.
-    d2 = (
-        jnp.sum(c * c, axis=2)[None]                              # (1, K, l)
-        - 2.0 * jnp.einsum("nkp,klp->nkl", subs, c)
-    )
-    return jnp.argmin(d2, axis=2).astype(jnp.uint8)
+    c2 = jnp.sum(c * c, axis=2)                                   # (K, l)
+
+    def encode_row(sub):                                          # (K, p)
+        # (K, l) squared distance; x-term constant wrt argmin.
+        d2 = c2 - 2.0 * jnp.einsum("kp,klp->kl", sub, c)
+        return jnp.argmin(d2, axis=1).astype(jnp.uint8)
+
+    return jax.lax.map(encode_row, subs, batch_size=16384)
 
 
 @jax.jit
 def pq_decode(codes: jax.Array, codebooks: PQCodebooks) -> jax.Array:
-    """Reconstruct (N, d) from (N, K) codes."""
+    """Reconstruct (N, d) from (N, K) codes.
+
+    One gather from the flat (K*l*p,) codebook table with (N, d) indices:
+    an (N, K, p) intermediate would have p (2 at K = d/2) as its minor
+    axis, which the TPU pads to 128 lanes (51 GB at 1M rows, K=100)."""
     c = codebooks.centers
     k, l, p = c.shape
-    recon = jnp.take_along_axis(
-        c[None], codes[:, :, None, None].astype(jnp.int32), axis=2
-    )                                                             # (N, K, 1, p)
-    return recon[:, :, 0, :].reshape(codes.shape[0], k * p)
+    dim = jnp.arange(k * p)
+    base = (dim // p) * (l * p) + dim % p                         # (d,)
+    codes_d = jnp.repeat(codes.astype(jnp.int32), p, axis=1)      # (N, d)
+    return c.reshape(-1)[base[None, :] + codes_d * p]
 
 
 @jax.jit

@@ -83,7 +83,7 @@ def _kernel(ptr_ref, col_ref, q_ref, tiles_ref, out_ref):
 def block_sparse_matmul_pallas(q: jax.Array, tiles: jax.Array,
                                tile_ptr: jax.Array, tile_col: jax.Array,
                                *, bq: int = 8, max_steps: int = 1,
-                               interpret: bool = True) -> jax.Array:
+                               interpret: bool) -> jax.Array:
     """q (Q, D) × BCSR head matrix -> (Q, N).  Q % bq == 0 (ops.py pads).
 
     ``max_steps`` bounds the per-row-block tile count (grid dim 2); pass the
@@ -173,7 +173,7 @@ def inverted_value_forward_pallas(ptr: jax.Array, rows: jax.Array,
                                   qidx: jax.Array, contrib: jax.Array, *,
                                   bq: int, bn: int, chunk: int,
                                   num_row_blocks: int, max_steps: int,
-                                  interpret: bool = True) -> jax.Array:
+                                  interpret: bool) -> jax.Array:
     """Value-forward inverted scoring over a host-planned stream.
 
     ptr (QB*(NB+1),) int32 chunk offsets per (query-block, row-block) —

@@ -1,7 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Handle padding to block multiples, host-side BCSR conversion, and the
-interpret-mode switch (interpret=True everywhere except a real TPU backend).
+interpret-mode switch (``lut16.default_interpret``: compiled on TPU,
+interpret mode on the CPU, an error anywhere else).
 """
 
 from __future__ import annotations
@@ -26,10 +27,10 @@ __all__ = ["lut16_adc", "lut16_adc_topk", "lut16_adc_onehot",
            "MAX_FUSED_CANDIDATES"]
 
 # Fused-select candidate-buffer cap (DESIGN.md §2.5): (bq, cbuf) score+id
-# buffers must stay VMEM-resident next to the (bq, bn) accumulator, and the
-# per-block merge is a top_k over (cbuf + bn) lanes — past ~1k candidates the
-# merge dominates the scan and materialize-then-topk wins anyway, so
-# lut16_adc_topk falls back above this.
+# buffers must stay VMEM-resident next to the (bq, bn) score block, and the
+# max-and-mask merge and final sort take one (bq, cbuf) pass per inserted or
+# sorted candidate — past ~1k candidates they dominate the scan and
+# materialize-then-topk wins anyway, so lut16_adc_topk falls back above this.
 MAX_FUSED_CANDIDATES = 1024
 
 
@@ -43,21 +44,15 @@ def _pad_to(x: np.ndarray | jax.Array, axis: int, mult: int, value=0):
     return jnp.pad(x, widths, constant_values=value), size
 
 
-def _resolve_lut16_blocks(q: int, n: int, kc: int, bq: int, bn: int,
-                          bk: int | None, packed: bool):
+def _resolve_lut16_blocks(q: int, n: int, bq: int, bn: int):
     """One block-size resolution for BOTH the materialize and the fused LUT16
     wrappers, so their per-block fp32 partial sums are bit-identical.
 
-    bk=None picks the stored-axis block: 32 bytes unpacked, 16 packed (one
-    packed byte is two subspaces, so this keeps the LUT VMEM block equal).
-    bn clamps against the 128-lane-rounded row count so small inputs aren't
-    padded to a full 512."""
-    if bk is None:
-        bk = 16 if packed else 32
+    bn clamps against the 128-lane-rounded row count so small inputs get
+    one (partial) block instead of a full 512."""
     bq = min(bq, max(1, q))
-    bk = min(bk, kc)
     bn = min(bn, max(-(-n // 128) * 128, 128))
-    return bq, bn, bk
+    return bq, bn
 
 
 def _validate_packed(kc: int, k: int, l: int, lut: jax.Array,
@@ -77,23 +72,16 @@ def _validate_packed(kc: int, k: int, l: int, lut: jax.Array,
 
 
 def lut16_adc(codes: jax.Array, lut: jax.Array, *, bq: int = 8, bn: int = 512,
-              bk: int | None = None, compute_dtype=jnp.float32,
-              packed: bool = False) -> jax.Array:
+              compute_dtype=jnp.float32, packed: bool = False) -> jax.Array:
     """LUT16 ADC: codes (N, K) uint8, lut (Q, K, l) or (K, l) -> (Q, N).
 
-    Pads N/Q/K to block multiples and routes through the Pallas kernel.
+    Pads Q to a block multiple and routes through the Pallas kernel (a
+    partial last row block needs no padding of the codes).
 
     packed=True: codes hold TWO 4-bit subspace codes per byte, shape
     (N, ceil(K/2)) from pack_codes — HBM streams half the bytes; the kernel
     unpacks in VMEM.  Requires l == 16.  Odd K is handled here by padding the
-    LUT with a zero phantom subspace so the pad nibble (code 0) scores 0.
-
-    bk=None picks the stored-axis block size: 32 bytes unpacked, 16 bytes
-    packed.  One packed byte is two logical subspaces, so the packed LUT
-    block spans 2*bk subspaces — halving bk keeps the per-step LUT VMEM
-    footprint (bq * 2*bk * l floats) identical to the unpacked kernel's
-    instead of doubling it (BENCH_serve.json records the resulting
-    packed-vs-unpacked QPS at Q in {1, 8, 32})."""
+    LUT with a zero phantom subspace so the pad nibble (code 0) scores 0."""
     single = lut.ndim == 2
     if single:
         lut = lut[None]
@@ -101,26 +89,19 @@ def lut16_adc(codes: jax.Array, lut: jax.Array, *, bq: int = 8, bn: int = 512,
     q, k, l = lut.shape
     n, kc = codes.shape                 # kc: stored (byte) subspace axis
     lut = _validate_packed(kc, k, l, lut, packed)
-    bq, bn, bk = _resolve_lut16_blocks(q, n, kc, bq, bn, bk, packed)
-    codes_p, n0 = _pad_to(jnp.asarray(codes), 0, bn)
-    # pad K consistently on both operands: padded codes point at LUT slot 0 of
-    # padded subspaces whose LUT is zero, contributing nothing.  (In packed
-    # form one padded byte is TWO zero-code phantom subspaces, so the LUT K
-    # axis pads by 2*bk per code byte.)
-    codes_p, _ = _pad_to(codes_p, 1, bk)
-    lut_p, _ = _pad_to(lut, 1, 2 * bk if packed else bk)
-    lut_p, q0 = _pad_to(lut_p, 0, bq)
-    out = lut16_adc_pallas(codes_p, lut_p, bq=bq, bn=bn, bk=bk,
+    bq, bn = _resolve_lut16_blocks(q, n, bq, bn)
+    lut_p, q0 = _pad_to(lut, 0, bq)
+    out = lut16_adc_pallas(jnp.asarray(codes), lut_p, bq=bq, bn=bn,
                            interpret=_interpret(), compute_dtype=compute_dtype,
                            packed=packed)
-    out = out[:q0, :n0]
+    out = out[:q0]
     return out[0] if single else out
 
 
 def lut16_adc_topk(codes: jax.Array, lut: jax.Array, k: int, *,
                    bias: jax.Array | None = None,
                    row_mask: jax.Array | None = None,
-                   bq: int = 8, bn: int = 512, bk: int | None = None,
+                   bq: int = 8, bn: int = 512,
                    compute_dtype=jnp.float32, packed: bool = False,
                    fused: bool = True):
     """Pass-1 scan-and-select: top-k of ``bias + row_mask + codes·lut``
@@ -145,70 +126,50 @@ def lut16_adc_topk(codes: jax.Array, lut: jax.Array, k: int, *,
     if not 0 < k <= n:
         raise ValueError(f"top-k needs 0 < k <= N rows, got k={k}, N={n}")
     lut = _validate_packed(kc, kl, l, lut, packed)
-    bq, bn, bk = _resolve_lut16_blocks(q, n, kc, bq, bn, bk, packed)
+    bq, bn = _resolve_lut16_blocks(q, n, bq, bn)
 
     def _normalize(s, ids):
         return s, jnp.where(jnp.isfinite(s), ids, -1)
 
+    base = None if bias is None else jnp.asarray(bias, jnp.float32)
+    if row_mask is not None:
+        rm = jnp.asarray(row_mask, jnp.float32)[None, :]
+        base = rm if base is None else base + rm
+
     if not (fused and k <= MAX_FUSED_CANDIDATES):
         # materialize-then-topk fallback: bias-first addition order matches
         # the fused kernel's select step bit-for-bit.
-        dense = lut16_adc(codes, lut[:, :kl], bq=bq, bn=bn, bk=bk,
+        dense = lut16_adc(codes, lut[:, :kl], bq=bq, bn=bn,
                           compute_dtype=compute_dtype, packed=packed)
-        base = bias
-        if row_mask is not None:
-            rm = jnp.asarray(row_mask, jnp.float32)[None, :]
-            base = rm if base is None else base + rm
         total = dense if base is None else base + dense
         s, ids = jax.lax.top_k(total, k)
         return _normalize(s, ids)
 
-    codes_p, _ = _pad_to(jnp.asarray(codes), 0, bn)
-    codes_p, _ = _pad_to(codes_p, 1, bk)
-    lut_p, _ = _pad_to(lut, 1, 2 * bk if packed else bk)
-    lut_p, _ = _pad_to(lut_p, 0, bq)
-    n_pad = codes_p.shape[0]
-    neg_inf = jnp.float32(-jnp.inf)
-    if bias is not None:
-        base = jnp.asarray(bias, jnp.float32)
-        if row_mask is not None:
-            base = base + jnp.asarray(row_mask, jnp.float32)[None, :]
-        # padded query rows get -inf too: their buffers stay (-inf, -1) and
-        # are sliced off below.
-        base = jnp.pad(base, ((0, lut_p.shape[0] - q), (0, n_pad - n)),
-                       constant_values=neg_inf)
-    else:
-        # no per-query bias: a (1, N) row mask is enough — the fused jaxpr
+    lut_p, _ = _pad_to(lut, 0, bq)
+    if base is None:
+        # no per-query bias: a (1, N) zero row is enough — the fused jaxpr
         # then contains NO (Q, N)-shaped value at all (the structural claim
         # dense_scores_materialized checks).
-        rm = (jnp.asarray(row_mask, jnp.float32) if row_mask is not None
-              else jnp.zeros((n,), jnp.float32))
-        base = jnp.pad(rm[None, :], ((0, 0), (0, n_pad - n)),
-                       constant_values=neg_inf)
-    s, ids = lut16_adc_topk_pallas(codes_p, lut_p, base, k=k, bq=bq, bn=bn,
-                                   bk=bk, interpret=_interpret(),
+        base = jnp.zeros((1, n), jnp.float32)
+    elif base.shape[0] > 1:
+        # padded query rows score -inf: their buffers stay (-inf, -1) and
+        # are sliced off below.
+        base = jnp.pad(base, ((0, lut_p.shape[0] - q), (0, 0)),
+                       constant_values=-jnp.inf)
+    s, ids = lut16_adc_topk_pallas(jnp.asarray(codes), lut_p, base, k=k,
+                                   bq=bq, bn=bn, interpret=_interpret(),
                                    compute_dtype=compute_dtype, packed=packed)
     return _normalize(s[:q, :k], ids[:q, :k])
 
 
-def _jaxpr_types():
-    try:                               # newer jax
-        from jax.extend import core as xcore
-        return xcore.Jaxpr, xcore.ClosedJaxpr
-    except (ImportError, AttributeError):
-        from jax import core as jcore
-        return jcore.Jaxpr, jcore.ClosedJaxpr
-
-
 def _walk_jaxpr_eqns(jaxpr):
-    Jaxpr, ClosedJaxpr = _jaxpr_types()
+    from jax.extend.core import ClosedJaxpr, Jaxpr
     for eqn in jaxpr.eqns:
         yield eqn
         if eqn.primitive.name == "pallas_call":
             # a pallas_call's body jaxpr manipulates VMEM *blocks*; only its
             # outvars (checked above) land in HBM.  Descending would flag
-            # per-block temporaries — e.g. the fused select's (bq, cbuf+bn)
-            # concat — that never exist at HBM scale.
+            # per-block temporaries that never exist at HBM scale.
             continue
         for v in eqn.params.values():
             for sub in jax.tree.leaves(

@@ -1,9 +1,12 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 # ^ MUST precede any jax-importing import: jax locks the device count at
 # first init, and the production meshes below need 512 host placeholders.
-# (Only the dry-run does this — tests and benches see 1 device.)
+# The dry-run is a host compile proof, so it pins itself to the CPU backend
+# and never takes a chip.  (Only the dry-run does this — tests and benches
+# see 1 device.)
 
 # Multi-pod dry-run: lower + compile every (architecture × input shape)
 # cell on the single-pod (16×16) and multi-pod (2×16×16) production meshes,

@@ -1,4 +1,5 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"        # a host compile probe, never a chip
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 
 # Perf-iteration probe: measure one cell's roofline terms quickly (unrolled

@@ -274,6 +274,8 @@ def main():
                          "§9.1).  In --role shard mode the flag is "
                          "forwarded to the shard server")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.role == "router":
         run_router(args)
     elif args.retrieval:

@@ -98,8 +98,7 @@ def _shardmap_combine(y_pad, slot, gates, b, s, k, d, e, c, dtype):
         y_tok = ya.reshape(ya.shape[0], s, k, d).sum(axis=2)
         return jax.lax.psum(y_tok, "model")
 
-    from repro import compat
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(bspec, "model", None), P(bspec, None), P(bspec, None)),
         out_specs=P(bspec, None, None), check_vma=False)

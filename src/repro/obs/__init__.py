@@ -23,7 +23,7 @@ from .metrics import (Counter, Gauge, Histogram,     # noqa: F401
 from .trace import (Span, Tracer, NULL_SPAN,         # noqa: F401
                     NULL_TRACER, STAGES, stage_totals)
 from .profile import (StepAnnotation, device_trace,  # noqa: F401
-                      pass_breakdown, profiler_available)
+                      pass_breakdown)
 from .exporter import MetricsServer, start_metrics_server  # noqa: F401
 
 __all__ = [
@@ -31,7 +31,7 @@ __all__ = [
     "Span", "Tracer", "NULL_SPAN", "NULL_TRACER", "NULL_COUNTER",
     "NULL_GAUGE", "NULL_HISTOGRAM", "NULL_REGISTRY", "DEFAULT_BOUNDS",
     "STAGES", "stage_totals", "StepAnnotation", "device_trace",
-    "pass_breakdown", "profiler_available", "MetricsServer",
+    "pass_breakdown", "MetricsServer",
     "start_metrics_server",
 ]
 

@@ -1,10 +1,6 @@
 """Profiling hooks (DESIGN.md §9.3): opt-in ``jax.profiler`` capture
 around engine dispatch, plus per-pass device-time attribution that
 feeds the roofline tables.
-
-Everything degrades to a no-op when ``jax.profiler`` is unavailable
-(CPU-only CI images, stubbed jax), so call sites never guard on
-imports.
 """
 
 from __future__ import annotations
@@ -12,61 +8,27 @@ from __future__ import annotations
 import contextlib
 import time
 
-__all__ = ["profiler_available", "device_trace", "StepAnnotation",
-           "pass_breakdown"]
+import jax.profiler
 
-
-def _profiler():
-    try:
-        import jax.profiler as p
-        return p
-    except Exception:
-        return None
-
-
-def profiler_available() -> bool:
-    """True when ``jax.profiler`` can be imported (a capture directory
-    will actually receive a trace)."""
-    return _profiler() is not None
+__all__ = ["device_trace", "StepAnnotation", "pass_breakdown"]
 
 
 @contextlib.contextmanager
 def device_trace(log_dir: str | None):
     """Context manager wrapping ``jax.profiler.trace(log_dir)`` around a
     region of engine dispatches.  A None ``log_dir`` (the default
-    everywhere — profiling is opt-in) or a missing profiler makes this a
-    no-op, so benchmarks can wrap their hot loops unconditionally."""
-    p = _profiler()
-    if log_dir is None or p is None:
+    everywhere — profiling is opt-in) makes this a no-op, so benchmarks
+    can wrap their hot loops unconditionally."""
+    if log_dir is None:
         yield
         return
-    p.start_trace(log_dir)
-    try:
+    with jax.profiler.trace(log_dir):
         yield
-    finally:
-        p.stop_trace()
 
 
-class StepAnnotation:
-    """``jax.profiler.StepTraceAnnotation`` with a no-op fallback: names
-    one engine dispatch inside a device trace so per-pass device time is
-    attributable in the captured timeline."""
-
-    def __init__(self, name: str, **kw):
-        p = _profiler()
-        self._inner = (p.StepTraceAnnotation(name, **kw)
-                       if p is not None and
-                       hasattr(p, "StepTraceAnnotation") else None)
-
-    def __enter__(self):
-        if self._inner is not None:
-            self._inner.__enter__()
-        return self
-
-    def __exit__(self, *exc):
-        if self._inner is not None:
-            return self._inner.__exit__(*exc)
-        return None
+# names one engine dispatch inside a device trace so per-pass device time
+# is attributable in the captured timeline
+StepAnnotation = jax.profiler.StepTraceAnnotation
 
 
 def pass_breakdown(engine, q_dims, q_vals, q_dense, *, h: int,

@@ -48,13 +48,8 @@ def predicted_pass1_bytes(*, q: int, n: int, k_codes: int, l: int = 16,
 def measured_bytes(fn, *args) -> float | None:
     """Compiler-reported "bytes accessed" for ``jit(fn)(*args)``.
 
-    Returns None when the backend's cost model doesn't expose the key (older
-    jax returns a list of dicts; missing key on CPU interpret lowerings)."""
+    Returns None when the backend's cost model doesn't expose the key
+    (missing on some CPU interpret lowerings)."""
     compiled = jax.jit(fn).lower(*args).compile()
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    if not isinstance(cost, dict):
-        return None
-    val = cost.get("bytes accessed")
+    val = compiled.cost_analysis().get("bytes accessed")
     return None if val is None else float(val)

@@ -117,6 +117,11 @@ class LocalCluster:
         env["PYTHONPATH"] = _src_path() + os.pathsep + \
             env.get("PYTHONPATH", "")
         env["PYTHONUNBUFFERED"] = "1"
+        # the child runs on the parent's platform or not at all: on a chip
+        # host whose chip this process already holds, the child fails at
+        # start-up (log tail below) instead of serving from the CPU
+        import jax
+        env["JAX_PLATFORMS"] = jax.default_backend()
         log_path = os.path.join(self.root, "logs", f"{name}.log")
         log = open(log_path, "ab")
         proc = subprocess.Popen(cmd, stdout=subprocess.PIPE, stderr=log,

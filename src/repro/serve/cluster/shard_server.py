@@ -799,6 +799,8 @@ def main(argv=None) -> int:
                     help="serve this node's metrics registry as a text "
                          "endpoint on the given port (0 = ephemeral)")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     server = ShardServer(args.role, store=args.store, peer=args.peer,
                          shard=args.shard, num_shards=args.num_shards,
                          workdir=args.workdir, backend=args.backend)

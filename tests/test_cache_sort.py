@@ -3,7 +3,7 @@
 import numpy as np
 import pytest
 import scipy.sparse as sp
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 import repro.core.cache_sort as cs
 

@@ -7,7 +7,7 @@ import pytest
 
 from repro.kernels.block_sparse import (block_sparse_matmul_pallas,
                                         dense_to_bcsr)
-from repro.kernels.lut16 import lut16_adc_pallas
+from repro.kernels.lut16 import default_interpret, lut16_adc_pallas
 from repro.kernels.ops import block_sparse_matmul, lut16_adc
 from repro.kernels.ref import (bcsr_to_dense_ref, block_sparse_ref,
                                lut16_adc_ref)
@@ -30,7 +30,8 @@ def test_lut16_shapes(n, k, l, q):
     codes = jnp.asarray(RNG.integers(0, l, (n, k)).astype(np.uint8))
     lut = jnp.asarray(RNG.normal(size=(q, k, l)).astype(np.float32))
     want = lut16_adc_ref(codes, lut)
-    got = lut16_adc_pallas(codes, lut, bq=min(8, q), bn=256, bk=min(8, k))
+    got = lut16_adc_pallas(codes, lut, bq=min(8, q), bn=256,
+                           interpret=default_interpret())
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-4)
 
@@ -40,7 +41,8 @@ def test_lut16_compute_dtypes(dtype):
     codes = jnp.asarray(RNG.integers(0, 16, (512, 16)).astype(np.uint8))
     lut = jnp.asarray(RNG.normal(size=(8, 16, 16)).astype(np.float32))
     want = np.asarray(lut16_adc_ref(codes, lut))
-    got = np.asarray(lut16_adc_pallas(codes, lut, bq=8, bn=256, bk=8,
+    got = np.asarray(lut16_adc_pallas(codes, lut, bq=8, bn=256,
+                                      interpret=default_interpret(),
                                       compute_dtype=dtype))
     tol = 1e-4 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(got, want, rtol=tol, atol=tol * 10)
@@ -75,7 +77,8 @@ def test_lut16_packed_4bit():
     want = lut16_adc_ref(jnp.asarray(codes), lut)
     packed = jnp.asarray(pack_codes(codes))
     assert packed.shape == (512, 8)
-    got = lut16_adc_pallas(packed, lut, bq=8, bn=256, bk=8, packed=True)
+    got = lut16_adc_pallas(packed, lut, bq=8, bn=256, packed=True,
+                           interpret=default_interpret())
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-4)
 
@@ -155,7 +158,8 @@ def test_block_sparse_shapes(n, d, br, bc, density):
     ms = int(np.max(ptr[1:] - ptr[:-1], initial=1))
     got = block_sparse_matmul_pallas(q, jnp.asarray(tiles), jnp.asarray(ptr),
                                      jnp.asarray(col), bq=8,
-                                     max_steps=max(ms, 1))
+                                     max_steps=max(ms, 1),
+                                     interpret=default_interpret())
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-4)
 

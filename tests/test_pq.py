@@ -3,7 +3,7 @@
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import pq
 
@@ -12,6 +12,23 @@ from repro.core import pq
 def gauss():
     rng = np.random.default_rng(0)
     return rng.normal(size=(3000, 32)).astype(np.float32)
+
+
+def test_train_codebooks_compiles_one_kmeans_program(gauss):
+    """The per-subspace seed is traced, not static: a build with many
+    subspaces compiles ONE k-means program, and the per-subspace keys still
+    make the codebooks deterministic under ``seed``."""
+    x = jnp.asarray(gauss)
+    pq._kmeans_one_subspace.clear_cache()
+    a = pq.train_codebooks(x, num_subspaces=16, num_codes=16, iters=3,
+                           seed=5)
+    assert pq._kmeans_one_subspace._cache_size() == 1
+    b = pq.train_codebooks(x, num_subspaces=16, num_codes=16, iters=3,
+                           seed=5)
+    np.testing.assert_array_equal(np.asarray(a.centers),
+                                  np.asarray(b.centers))
+    assert not np.array_equal(np.asarray(a.centers[0]),
+                              np.asarray(a.centers[1]))
 
 
 def test_encode_decode_reduces_mse(gauss):

@@ -3,7 +3,7 @@
 import jax.numpy as jnp
 import numpy as np
 import scipy.sparse as sp
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import pruning
 from repro.core import sparse_index as si

@@ -28,7 +28,7 @@ import numpy as np
 import pytest
 import scipy.sparse as sp
 
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.engine import ScoringEngine, tombstone_mask
 from repro.core.hybrid import HybridIndex, HybridIndexParams
