@@ -27,9 +27,6 @@ Emits CSV rows like the other benchmark modules AND writes
                              span_sourced} — baseline is Observability.off(),
                              disabled the default bundle, enabled adds
                              tracing; CI floors the ratios (>= 0.97 / 0.90)
-    profile                  per-pass device-time attribution (§9.3):
-                             {pass1_s, full_s, pass23_s, pass1_fraction,
-                             iters, backend}
     smoke                    true when run with --smoke (CI scale)
 
 ``--stream`` instead runs the streaming-mutation workload (DESIGN.md §6)
@@ -87,7 +84,7 @@ from repro.core.hybrid import HybridIndex, HybridIndexParams
 from repro.core.pq import pack_codes
 from repro.core.sparse_index import sparse_queries_to_padded
 from repro.data import make_hybrid_dataset
-from repro.obs import Observability, device_trace, pass_breakdown
+from repro.obs import Observability
 from repro.serve import QueryService
 
 from .common import emit, timeit
@@ -157,7 +154,8 @@ def _obs_overhead(idx, q_dims, q_vals, q_dense, repeat):
     (metrics + tracing) — measured in INTERLEAVED best-of rounds so
     machine drift hits every mode equally.  Returns the qps per mode, the
     ratios vs baseline (CI floors: disabled >= 0.97, enabled >= 0.90),
-    and a span-sourced dispatch/merge breakdown from the enabled mode."""
+    and a span-sourced dispatch / device-wait / merge breakdown from the
+    enabled mode."""
     modes = {"baseline": Observability.off(),
              "disabled": None,       # service default bundle
              "enabled": Observability(metrics=True, trace=True)}
@@ -180,17 +178,21 @@ def _obs_overhead(idx, q_dims, q_vals, q_dense, repeat):
             if best[k] is None or dt < best[k]:
                 best[k] = dt
     qps = {k: nq / v for k, v in best.items()}
-    # span-sourced serve breakdown: sum the serve.batch children's
-    # dispatch/merge tags over the enabled mode's measured traces
+    # span-sourced serve breakdown: sum the durations of each serve.batch
+    # span's dispatch / device-wait / merge children over the enabled
+    # mode's measured traces
     traces = svcs["enabled"].obs.tracer.take()
-    disp = merge = 0.0
+    stage = dict.fromkeys(("serve.dispatch", "serve.device_wait",
+                           "serve.merge"), 0.0)
     nbatch = 0
     for t in traces:
         for c in t.get("children", ()):
-            tags = c.get("tags", {})
-            disp += tags.get("dispatch_s", 0.0)
-            merge += tags.get("merge_s", 0.0)
+            if c["name"] != "serve.batch":
+                continue
             nbatch += 1
+            for g in c.get("children", ()):
+                if g["name"] in stage:
+                    stage[g["name"]] += g["duration_s"]
     served = nq * len(traces) or 1
     for s in svcs.values():
         s.close()
@@ -199,13 +201,16 @@ def _obs_overhead(idx, q_dims, q_vals, q_dense, repeat):
             "enabled_qps": qps["enabled"],
             "disabled_ratio": qps["disabled"] / qps["baseline"],
             "enabled_ratio": qps["enabled"] / qps["baseline"],
-            "breakdown": {"dispatch_us_per_q": disp / served * 1e6,
-                          "merge_us_per_q": merge / served * 1e6,
-                          "traces": len(traces), "batches": nbatch},
+            "breakdown": {
+                "dispatch_us_per_q": stage["serve.dispatch"] / served * 1e6,
+                "device_wait_us_per_q":
+                    stage["serve.device_wait"] / served * 1e6,
+                "merge_us_per_q": stage["serve.merge"] / served * 1e6,
+                "traces": len(traces), "batches": nbatch},
             "span_sourced": True}
 
 
-def main(smoke: bool = False, profile_dir: str | None = None):
+def main(smoke: bool = False):
     """Run the serving benches; prints CSV rows and writes BENCH_serve.json."""
     repeat = 2 if smoke else 5
     ds, idx, q_dims, q_vals, q_dense = _build(smoke)
@@ -255,16 +260,6 @@ def main(smoke: bool = False, profile_dir: str | None = None):
          f"disabled_ratio={obs['disabled_ratio']:.3f};"
          f"enabled_ratio={obs['enabled_ratio']:.3f}")
 
-    # -- per-pass device-time attribution (DESIGN.md §9.3) ----------------
-    with device_trace(profile_dir):
-        prof = pass_breakdown(idx.engine, jnp.asarray(q_dims),
-                              jnp.asarray(q_vals), jnp.asarray(q_dense),
-                              h=H, alpha=ALPHA, beta=BETA,
-                              iters=2 if smoke else 3)
-    emit("serve_pass_breakdown", prof["full_s"] * 1e6,
-         f"pass1_fraction={prof['pass1_fraction']:.3f};"
-         f"pass1_us={prof['pass1_s'] * 1e6:.0f}")
-
     # -- refresh pause ----------------------------------------------------
     idx2 = HybridIndex.build(ds.x_sparse, ds.x_dense,
                              dataclasses.replace(idx.params, seed=11))
@@ -308,7 +303,6 @@ def main(smoke: bool = False, profile_dir: str | None = None):
         "refresh": {"swap_s": swap_s, "first_search_after_s": first_after_s},
         "packed": packed,
         "obs": obs,
-        "profile": prof,
         "smoke": smoke,
     }
     with open(OUT_JSON, "w") as f:
@@ -481,14 +475,9 @@ if __name__ == "__main__":
     ap.add_argument("--stream", action="store_true",
                     help="run the streaming-mutation workload instead "
                          "(writes BENCH_stream.json)")
-    ap.add_argument("--profile-dir", default=None,
-                    help="capture a jax.profiler device trace of the "
-                         "pass-breakdown probe into this directory "
-                         "(DESIGN.md §9.3; no-op when the profiler is "
-                         "unavailable)")
     args = ap.parse_args()
     if args.stream:
         print("name,us_per_call,derived")
         stream_main(smoke=args.smoke)
     else:
-        main(smoke=args.smoke, profile_dir=args.profile_dir)
+        main(smoke=args.smoke)

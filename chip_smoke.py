@@ -95,7 +95,7 @@ def run_one_chip(rows: int, seed: int) -> None:
     import jax
     import numpy as np
 
-    from repro.core.engine import Backend, ScoringEngine, three_pass_search
+    from repro.core.engine import Backend, ScoringEngine
     from repro.core.hybrid import HybridIndex
     from repro.core.sparse_index import sparse_queries_to_padded
     from repro.serve import QueryService
@@ -144,11 +144,7 @@ def run_one_chip(rows: int, seed: int) -> None:
     with phase("compiled_text", walls):
         qd, qv, qe = (jax.numpy.asarray(x[:32]) for x in
                       (q_dims, q_vals, q_dense))
-        c1, c2 = idx.engine.candidate_counts(H, ALPHA, BETA)
-        hlo = three_pass_search.lower(
-            arrays, qd, qv, qe, h=H, c1=c1, c2=c2,
-            backend=idx.engine.backend, fused=idx.engine.fused
-        ).compile().as_text()
+        hlo = idx.engine.search_hlo(qd, qv, qe, h=H, alpha=ALPHA, beta=BETA)
     check("tpu_custom_call" in hlo,
           "the compiled search program contains tpu_custom_call")
 

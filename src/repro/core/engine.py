@@ -245,12 +245,15 @@ def pass1_bias(arrays: IndexArrays, q_dims: jax.Array, q_vals: jax.Array,
     This is the per-(query, row) additive bias the fused scan-and-select
     kernel (DESIGN.md §2.5) folds into its select step — the dense ADC term
     and the tombstone mask are NOT included here."""
-    sparse = score_inverted(arrays.inv_index, q_dims, q_vals)
-    if arrays.head is not None:
-        q_head = scatter_head_queries(q_dims, q_vals, arrays.head_pos,
-                                      arrays.head.block.shape[1])
-        head_s = _head_scores(arrays, q_head, backend)
-        sparse = sparse + head_s[:, : arrays.num_points]
+    with jax.named_scope("pass1.sparse_bias"):
+        with jax.named_scope("inverted"):
+            sparse = score_inverted(arrays.inv_index, q_dims, q_vals)
+        if arrays.head is not None:
+            with jax.named_scope("head"):
+                q_head = scatter_head_queries(q_dims, q_vals, arrays.head_pos,
+                                              arrays.head.block.shape[1])
+                head_s = _head_scores(arrays, q_head, backend)
+                sparse = sparse + head_s[:, : arrays.num_points]
     return sparse
 
 
@@ -267,10 +270,12 @@ def pass1_scores(arrays: IndexArrays, q_dims: jax.Array, q_vals: jax.Array,
     bit-identical to masking last — and matches the fused kernel's
     bias-at-select ordering.)"""
     bias = pass1_bias(arrays, q_dims, q_vals, backend)
-    if arrays.valid_mask is not None:
-        bias = bias + arrays.valid_mask[None, :]
-    dense = adc_scores(arrays.codes, lut, backend, packed=arrays.codes_packed)
-    return bias + dense
+    with jax.named_scope("pass1.scan"):
+        if arrays.valid_mask is not None:
+            bias = bias + arrays.valid_mask[None, :]
+        dense = adc_scores(arrays.codes, lut, backend,
+                           packed=arrays.codes_packed)
+        return bias + dense
 
 
 def tombstone_mask(capacity: int, count: int,
@@ -306,9 +311,10 @@ def _fused_pass1_topk(arrays: IndexArrays, q_dims: jax.Array,
     pass1_scores + top_k."""
     from repro.kernels.ops import lut16_adc_topk
     bias = pass1_bias(arrays, q_dims, q_vals, backend)
-    return lut16_adc_topk(arrays.codes, lut, k, bias=bias,
-                          row_mask=arrays.valid_mask,
-                          packed=arrays.codes_packed)
+    with jax.named_scope("pass1.scan"):
+        return lut16_adc_topk(arrays.codes, lut, k, bias=bias,
+                              row_mask=arrays.valid_mask,
+                              packed=arrays.codes_packed)
 
 
 @partial(jax.jit, static_argnames=("h", "c1", "c2", "backend", "fused"))
@@ -322,24 +328,37 @@ def three_pass_search(arrays: IndexArrays, q_dims: jax.Array,
 
     ``fused`` (default on) routes pass 1 through the fused scan-and-select
     kernel on the Pallas backends whenever c1 fits the candidate buffer —
-    same (scores, ids) bit-for-bit, minus the (Q, N) HBM round-trip."""
-    lut = adc_lut(q_dense, arrays.codebooks)
+    same (scores, ids) bit-for-bit, minus the (Q, N) HBM round-trip.
+
+    Each layer runs under a ``jax.named_scope`` — ``pass1.lut``,
+    ``pass1.sparse_bias`` (``inverted`` and ``head`` inside it),
+    ``pass1.scan``, ``pass2``, ``pass3`` — which the compiled program's
+    instruction metadata carries (``ScoringEngine.search_hlo``), so device
+    time in a profiler trace can be attributed to a layer (DESIGN.md §9.3).
+    The scopes cost nothing at run time."""
+    with jax.named_scope("pass1.lut"):
+        lut = adc_lut(q_dense, arrays.codebooks)
 
     # pass 1: approximate scores on the full shard, overfetch c1
     if _use_fused_pass1(arrays, backend, fused, c1):
         s1, ids1 = _fused_pass1_topk(arrays, q_dims, q_vals, lut, c1, backend)
     else:
         approx = pass1_scores(arrays, q_dims, q_vals, lut, backend)
-        s1, ids1 = res.topk_candidates(approx, c1)
+        with jax.named_scope("pass1.scan"):
+            s1, ids1 = res.topk_candidates(approx, c1)
 
     # pass 2: + dense residual, keep c2
-    extra_d = res.dense_residual_scores(arrays.dense_residual, ids1, q_dense)
-    s2, ids2 = res.reorder_pass(s1, ids1, extra_d, c2)
+    with jax.named_scope("pass2"):
+        extra_d = res.dense_residual_scores(arrays.dense_residual, ids1,
+                                            q_dense)
+        s2, ids2 = res.reorder_pass(s1, ids1, extra_d, c2)
 
     # pass 3: + sparse residual, return h
-    q_cols = scatter_queries_compact(q_dims, q_vals, arrays.d_active)
-    extra_s = res.sparse_residual_scores(arrays.sparse_residual, ids2, q_cols)
-    s3, ids3 = res.reorder_pass(s2, ids2, extra_s, h)
+    with jax.named_scope("pass3"):
+        q_cols = scatter_queries_compact(q_dims, q_vals, arrays.d_active)
+        extra_s = res.sparse_residual_scores(arrays.sparse_residual, ids2,
+                                             q_cols)
+        s3, ids3 = res.reorder_pass(s2, ids2, extra_s, h)
     return s3, ids3, ids1
 
 
@@ -387,6 +406,18 @@ class ScoringEngine:
         return three_pass_search(self.arrays, q_dims, q_vals, q_dense,
                                  h=h, c1=c1, c2=c2, backend=self.backend,
                                  fused=self.fused)
+
+    def search_hlo(self, q_dims, q_vals, q_dense, *, h: int, alpha: int,
+                   beta: int) -> str:
+        """Optimized HLO text of the program ``search`` runs for queries of
+        these shapes (arrays or ``jax.ShapeDtypeStruct``) and ``(h, alpha,
+        beta)``.  Each instruction's ``metadata={op_name=...}`` names the
+        ``three_pass_search`` scope it came from; a profiler trace names
+        device operations by the same instruction names."""
+        c1, c2 = self.candidate_counts(h, alpha, beta)
+        return three_pass_search.lower(
+            self.arrays, q_dims, q_vals, q_dense, h=h, c1=c1, c2=c2,
+            backend=self.backend, fused=self.fused).compile().as_text()
 
     def pass1_topk(self, q_dims: jax.Array, q_vals: jax.Array,
                    lut: jax.Array, k: int):

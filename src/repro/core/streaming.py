@@ -44,12 +44,13 @@ shard fan-out.
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import scipy.sparse as sp
+
+from repro.obs import NULL_SPAN
 
 from .distributed import ceil16, merge_topk_host
 from .engine import IndexArrays, ScoringEngine, tombstone_mask
@@ -619,7 +620,7 @@ def plan_overfetch(engines, h: int, deleted) -> list[int]:
 def fanout_search(engines, h_fetch, offsets, id_map, delta_engine,
                   delta_ids, deleted, qd, qv, qe, *, h: int, alpha: int,
                   beta: int, qn: int | None = None, executor=None,
-                  dedup_upserts: bool = False, timing: dict | None = None):
+                  dedup_upserts: bool = False, span=NULL_SPAN):
     """THE fan-out merge (DESIGN.md §6.2): dispatch every main engine plus
     the delta engine back-to-back (JAX async dispatch overlaps them — the
     in-process form of the paper's §7.2 RPC fan-out), assemble the per-
@@ -647,61 +648,58 @@ def fanout_search(engines, h_fetch, offsets, id_map, delta_engine,
     in-process path leaves it None because JAX async dispatch already
     overlaps device work.  ``dedup_upserts`` forwards to
     ``merge_topk_host`` (see its docstring for the cross-transport upsert
-    race it closes).  ``timing``, when a dict, receives ``dispatch_s``
-    (dispatch + collect of every engine) and ``merge_s`` (host assembly +
-    top-h merge) wall seconds — the span tags ``QueryService`` feeds its
-    ``serve.batch`` children (DESIGN.md §9.2; note JAX async dispatch can
-    defer device sync into the assembly step, so on the in-process path
-    ``merge_s`` includes the device wait).  Returns ``(scores, ids)
-    (qn, h)`` numpy arrays.
+    race it closes).  ``span`` (a ``repro.obs`` span, ``QueryService``'s
+    ``serve.batch``) gets three children, one per stage (DESIGN.md §9.2):
+    ``serve.dispatch`` (every engine's ``search`` call), ``serve.device_wait``
+    (until every engine's output is ready) and ``serve.merge`` (host
+    assembly + top-h merge, the device wait excluded).  Returns ``(scores,
+    ids) (qn, h)`` numpy arrays.
     """
-    t0 = time.perf_counter()
-    if executor is not None:
-        futs = [executor.submit(e.search, qd, qv, qe, h=hf,
-                                alpha=alpha, beta=beta)
-                for e, hf in zip(engines, h_fetch)]
-        dfut = None
-        if delta_engine is not None:
-            dfut = executor.submit(delta_engine.search, qd, qv, qe,
-                                   h=delta_engine.num_points,
-                                   alpha=alpha, beta=beta)
-        outs = [f.result() for f in futs]
-        delta_out = dfut.result() if dfut is not None else None
-    else:
-        outs = [e.search(qd, qv, qe, h=hf, alpha=alpha, beta=beta)
-                for e, hf in zip(engines, h_fetch)]
-        delta_out = None
-        if delta_engine is not None:
-            delta_out = delta_engine.search(qd, qv, qe,
-                                            h=delta_engine.num_points,
-                                            alpha=alpha, beta=beta)
-    t1 = time.perf_counter()
-    # assemble per-engine candidate parts in a COMMON id space.  Shards
-    # stay in row order so stable-sort tie-breaking matches lax.top_k on
-    # the unsharded array.
-    parts = []
-    for out, off in zip(outs, offsets):
-        s = np.asarray(out[0])
-        ids = np.asarray(out[1]).astype(np.int64)
-        if qn is not None:
-            s, ids = s[:qn], ids[:qn]
-        ids = ids + int(off)
-        if id_map is not None:
-            ids = np.asarray(id_map)[ids]
-        parts.append((s, ids, True))
-    if delta_out is not None:
-        s = np.asarray(delta_out[0])
-        pos = np.asarray(delta_out[1]).astype(np.int64)
-        if qn is not None:
-            s, pos = s[:qn], pos[:qn]
-        parts.append((s, pos if delta_ids is None else delta_ids[pos],
-                      False))
-    out = merge_topk_host(parts, h, drop_ids=deleted,
-                          dedup_upserts=dedup_upserts)
-    if timing is not None:
-        timing["dispatch_s"] = t1 - t0
-        timing["merge_s"] = time.perf_counter() - t1
-    return out
+    with span.child("serve.dispatch"):
+        if executor is not None:
+            futs = [executor.submit(e.search, qd, qv, qe, h=hf,
+                                    alpha=alpha, beta=beta)
+                    for e, hf in zip(engines, h_fetch)]
+            dfut = None
+            if delta_engine is not None:
+                dfut = executor.submit(delta_engine.search, qd, qv, qe,
+                                       h=delta_engine.num_points,
+                                       alpha=alpha, beta=beta)
+            outs = [f.result() for f in futs]
+            delta_out = dfut.result() if dfut is not None else None
+        else:
+            outs = [e.search(qd, qv, qe, h=hf, alpha=alpha, beta=beta)
+                    for e, hf in zip(engines, h_fetch)]
+            delta_out = None
+            if delta_engine is not None:
+                delta_out = delta_engine.search(qd, qv, qe,
+                                                h=delta_engine.num_points,
+                                                alpha=alpha, beta=beta)
+    with span.child("serve.device_wait"):
+        jax.block_until_ready((outs, delta_out))
+    with span.child("serve.merge"):
+        # assemble per-engine candidate parts in a COMMON id space.
+        # Shards stay in row order so stable-sort tie-breaking matches
+        # lax.top_k on the unsharded array.
+        parts = []
+        for out, off in zip(outs, offsets):
+            s = np.asarray(out[0])
+            ids = np.asarray(out[1]).astype(np.int64)
+            if qn is not None:
+                s, ids = s[:qn], ids[:qn]
+            ids = ids + int(off)
+            if id_map is not None:
+                ids = np.asarray(id_map)[ids]
+            parts.append((s, ids, True))
+        if delta_out is not None:
+            s = np.asarray(delta_out[0])
+            pos = np.asarray(delta_out[1]).astype(np.int64)
+            if qn is not None:
+                s, pos = s[:qn], pos[:qn]
+            parts.append((s, pos if delta_ids is None else delta_ids[pos],
+                          False))
+        return merge_topk_host(parts, h, drop_ids=deleted,
+                               dedup_upserts=dedup_upserts)
 
 
 def search_mutable(index, q_sparse, q_dense, h: int = 20,

@@ -5,9 +5,8 @@ request-scoped tracing + profiling hooks, shared by ``QueryService``,
 * ``metrics`` — lock-cheap counters/gauges/bounded-bucket histograms
   behind one :class:`MetricsRegistry` (§9.1);
 * ``trace`` — per-request :class:`Span` trees propagated across the
-  cluster wire via frame meta (§9.2);
-* ``profile`` — opt-in ``jax.profiler`` capture + per-pass device-time
-  attribution (§9.3);
+  cluster wire via frame meta, mirrored onto the profiler's timeline when
+  used as context managers (§9.2, §9.3);
 * ``exporter`` — the ``--metrics-port`` text endpoint.
 
 :class:`Observability` bundles one registry + one tracer and is the
@@ -22,17 +21,13 @@ from .metrics import (Counter, Gauge, Histogram,     # noqa: F401
                       NULL_HISTOGRAM, NULL_REGISTRY, DEFAULT_BOUNDS)
 from .trace import (Span, Tracer, NULL_SPAN,         # noqa: F401
                     NULL_TRACER, STAGES, stage_totals)
-from .profile import (StepAnnotation, device_trace,  # noqa: F401
-                      pass_breakdown)
 from .exporter import MetricsServer, start_metrics_server  # noqa: F401
 
 __all__ = [
     "Observability", "MetricsRegistry", "Counter", "Gauge", "Histogram",
     "Span", "Tracer", "NULL_SPAN", "NULL_TRACER", "NULL_COUNTER",
     "NULL_GAUGE", "NULL_HISTOGRAM", "NULL_REGISTRY", "DEFAULT_BOUNDS",
-    "STAGES", "stage_totals", "StepAnnotation", "device_trace",
-    "pass_breakdown", "MetricsServer",
-    "start_metrics_server",
+    "STAGES", "stage_totals", "MetricsServer", "start_metrics_server",
 ]
 
 
@@ -47,11 +42,9 @@ class Observability:
     measurement, not production serving (DESIGN.md §9.4)."""
 
     def __init__(self, *, metrics: bool = True, trace: bool = False,
-                 keep_traces: int = 256,
-                 profile_dir: str | None = None):
+                 keep_traces: int = 256):
         self.metrics = MetricsRegistry(enabled=metrics)
         self.tracer = Tracer(enabled=trace, keep=keep_traces)
-        self.profile_dir = profile_dir
 
     @property
     def enabled(self) -> bool:

@@ -18,6 +18,13 @@ the serialized result under ``rmeta["trace"]``, which the client folds
 back in with ``Span.attach_remote``.  Requests without a ``trace`` key
 cost the server nothing — server-side tracing is opt-in per request.
 
+A span used as a context manager (``with span:``, ``with
+span.child(...):``) also opens a ``jax.profiler.TraceAnnotation`` of its
+name on the entering thread, so while a profiler captures, the span sits on
+the device trace's clock beside the device's operations.  Spans ended
+explicitly (``end()``, possibly on another thread) keep their tags and stay
+off that timeline.
+
 The disabled path is the ``NULL_SPAN`` singleton: every method is a
 no-op, ``child()`` returns itself, ``wire_context()`` returns None (so
 nothing is added to request meta), and ``bool(NULL_SPAN)`` is False.
@@ -32,11 +39,14 @@ import os
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["Span", "Tracer", "NULL_SPAN", "NULL_TRACER", "stage_totals",
            "STAGES"]
 
 # The per-hop stage vocabulary (DESIGN.md §9.2): tag names ending in
-# ``_s`` on "rpc" child spans, plus "merge_s" on the root.
+# ``_s`` on "rpc" child spans, plus "merge_s" on the root; QueryService's
+# serve.search root carries "queue_s" when it came through submit().
 STAGES = ("serialize_s", "wire_s", "queue_s", "score_s", "merge_s")
 
 
@@ -47,11 +57,12 @@ def _new_id() -> str:
 class Span:
     """One timed unit of work in a trace tree.  Create roots via
     :meth:`Tracer.root`; children via :meth:`child`.  Usable as a
-    context manager (``__exit__`` calls :meth:`end`)."""
+    context manager: the block is also a profiler ``TraceAnnotation`` of
+    the span's name, and ``__exit__`` calls :meth:`end`."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "start_s",
                  "duration_s", "tags", "annotations", "children",
-                 "_tracer")
+                 "_tracer", "_annotation")
 
     def __init__(self, name: str, *, trace_id: str | None = None,
                  parent_id: str | None = None, tracer=None, **tags):
@@ -65,6 +76,7 @@ class Span:
         self.annotations: list[str] = []
         self.children: list[Span] = []
         self._tracer = tracer
+        self._annotation = None
 
     def __bool__(self) -> bool:
         return True
@@ -119,9 +131,14 @@ class Span:
             self._tracer = None
 
     def __enter__(self) -> "Span":
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc) -> None:
+        annotation, self._annotation = self._annotation, None
+        if annotation is not None:
+            annotation.__exit__(*exc)
         self.end()
 
     def to_wire(self) -> dict:

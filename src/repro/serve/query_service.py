@@ -74,7 +74,7 @@ from repro.core.engine import (Backend, IndexArrays, ScoringEngine,
                                query_fingerprint, release_index_arrays)
 from repro.core.sparse_index import sparse_queries_to_padded
 from repro.core.streaming import fanout_search, plan_overfetch
-from repro.obs import Observability
+from repro.obs import NULL_SPAN, Observability
 
 __all__ = ["QueryService", "CacheInfo", "JitCacheInfo", "bucket_for",
            "pad_rows"]
@@ -233,8 +233,11 @@ class QueryService:
         The :class:`repro.obs.Observability` bundle (DESIGN.md §9): its
         registry backs ``cache_info()``/``stats()``/``metrics()`` and the
         WAL durability instruments; its tracer (off by default) emits one
-        ``serve.search`` root span per request with ``serve.batch``
-        children.  ``Observability.off()`` nulls everything — the no-obs
+        ``serve.search`` root span per request (``queue_s`` tagged when it
+        came through ``submit``) with ``serve.cache`` and ``serve.batch``
+        children, each batch with ``serve.pad``, ``serve.dispatch``,
+        ``serve.device_wait`` and ``serve.merge`` children (DESIGN.md
+        §9.2).  ``Observability.off()`` nulls everything — the no-obs
         baseline for overhead measurement (§9.4).
     """
 
@@ -265,6 +268,9 @@ class QueryService:
         self._c_requests = m.counter("serve.requests")
         self._c_batches = m.counter("serve.batches")
         self._c_refreshes = m.counter("serve.refreshes")
+        self._c_rows = m.counter("serve.rows")
+        self._c_bucket_rows = m.counter("serve.bucket_rows")
+        self._c_new_programs = m.counter("serve.new_programs")
         self._h_compact = m.histogram("serve.compact_s")
         self._g_delta = m.gauge("serve.delta_rows")
         self._durability = None
@@ -682,7 +688,8 @@ class QueryService:
     # -- request path -----------------------------------------------------
 
     def search(self, q_dims, q_vals, q_dense, *, h: int | None = None,
-               alpha: int | None = None, beta: int | None = None
+               alpha: int | None = None, beta: int | None = None,
+               _queued_at: float | None = None
                ) -> tuple[np.ndarray, np.ndarray]:
         """Serve a batch of hybrid queries through cache + bucketed engine.
 
@@ -697,21 +704,27 @@ class QueryService:
         compact column space of the generation they were encoded against,
         which changes at every compaction — streaming clients should call
         ``search_sparse`` (raw queries, per-generation encoding) instead of
-        caching padded queries across mutations."""
+        caching padded queries across mutations.
+
+        ``_queued_at`` is set by ``submit``: its clock reading when the
+        request was queued, for the root span's ``queue_s``."""
+        queue_s = (None if _queued_at is None
+                   else time.perf_counter() - _queued_at)
         q_dims = np.atleast_2d(np.asarray(q_dims, np.int32))
         q_vals = np.atleast_2d(np.asarray(q_vals, np.float32))
         q_dense = np.atleast_2d(np.asarray(q_dense, np.float32))
         gen, view, mut_version, _ = self._acquire_view()
         try:
             return self._serve(gen, view, mut_version, q_dims, q_vals,
-                               q_dense, h, alpha, beta)
+                               q_dense, h, alpha, beta, queue_s)
         finally:
             self._release(gen)
 
     def _serve(self, gen: _Generation, view: "_DeltaView | None",
                mut_version: int, q_dims: np.ndarray, q_vals: np.ndarray,
                q_dense: np.ndarray, h: int | None, alpha: int | None,
-               beta: int | None) -> tuple[np.ndarray, np.ndarray]:
+               beta: int | None, queue_s: float | None = None
+               ) -> tuple[np.ndarray, np.ndarray]:
         """Cache + batch + fan-out against an already-pinned generation
         (the caller holds the refcount)."""
         h = self.h if h is None else h
@@ -723,28 +736,33 @@ class QueryService:
         # BOTH the generation and the delta-shard mutation version —
         # a cached hit can never serve pre-insert/pre-delete results.
         use_cache = self._cache_cap > 0
-        keys = [query_fingerprint(q_dims[i], q_vals[i], q_dense[i],
-                                  h, alpha, beta, gen.version, mut_version)
-                for i in range(qn)] if use_cache else None
         out_s = np.empty((qn, h), np.float32)
         out_i = np.empty((qn, h), np.int64)
         sp = self.obs.tracer.root("serve.search", qn=qn, h=h,
                                   gen=gen.version)
+        if queue_s is not None:
+            sp.set("queue_s", queue_s)
         hits = evictions = 0
+        keys = None
         with sp:
-            with self._lock:
-                if not use_cache:
-                    miss = list(range(qn))
-                else:
+            if not use_cache:
+                miss = list(range(qn))
+            else:
+                with sp.child("serve.cache"):
+                    keys = [query_fingerprint(q_dims[i], q_vals[i],
+                                              q_dense[i], h, alpha, beta,
+                                              gen.version, mut_version)
+                            for i in range(qn)]
                     miss = []
-                    for i, key in enumerate(keys):
-                        hit = self._cache.get(key)
-                        if hit is not None:
-                            self._cache.move_to_end(key)
-                            hits += 1
-                            out_s[i], out_i[i] = hit
-                        else:
-                            miss.append(i)
+                    with self._lock:
+                        for i, key in enumerate(keys):
+                            hit = self._cache.get(key)
+                            if hit is not None:
+                                self._cache.move_to_end(key)
+                                hits += 1
+                                out_s[i], out_i[i] = hit
+                            else:
+                                miss.append(i)
             max_bucket = self.buckets[-1]
             for lo in range(0, len(miss), max_bucket):
                 rows = miss[lo:lo + max_bucket]
@@ -782,19 +800,21 @@ class QueryService:
 
         Dispatch order is submission order on a small worker pool; the shard
         fan-out inside each search already overlaps device work via JAX
-        async dispatch."""
+        async dispatch.  The time from here to a worker entering ``search``
+        is the root span's ``queue_s``."""
         with self._lock:
             if self._executor is None:
                 self._executor = ThreadPoolExecutor(
                     max_workers=self._max_workers,
                     thread_name_prefix="query-service")
             ex = self._executor
-        return ex.submit(self.search, q_dims, q_vals, q_dense, **kw)
+        return ex.submit(self.search, q_dims, q_vals, q_dense,
+                         _queued_at=time.perf_counter(), **kw)
 
     def _run_batch(self, gen: _Generation, view: _DeltaView | None,
                    q_dims: np.ndarray, q_vals: np.ndarray,
                    q_dense: np.ndarray, h: int, alpha: int, beta: int,
-                   span=None) -> tuple[np.ndarray, np.ndarray]:
+                   span=NULL_SPAN) -> tuple[np.ndarray, np.ndarray]:
         """Pad one miss-batch to its bucket, fan out over the main engine(s)
         plus the delta shard, merge on host.
 
@@ -808,9 +828,10 @@ class QueryService:
         qn = q_dims.shape[0]
         bucket = bucket_for(qn, self.buckets)
         d_active = gen.engine.arrays.d_active
-        qd = jnp.asarray(pad_rows(q_dims, bucket, fill=d_active))
-        qv = jnp.asarray(pad_rows(q_vals, bucket))
-        qe = jnp.asarray(pad_rows(q_dense, bucket))
+        with span.child("serve.pad"):
+            qd = jnp.asarray(pad_rows(q_dims, bucket, fill=d_active))
+            qv = jnp.asarray(pad_rows(q_vals, bucket))
+            qe = jnp.asarray(pad_rows(q_dense, bucket))
 
         deleted = view.deleted if view is not None else frozenset()
         engines = gen.shards if gen.shards is not None else [gen.engine]
@@ -820,31 +841,35 @@ class QueryService:
         delta_engine = view.engine if view is not None else None
 
         self._c_batches.inc()
+        self._c_rows.inc(qn)
+        self._c_bucket_rows.inc(bucket)
+        c1, c2 = engines[0].candidate_counts(h_fetch[0], alpha, beta)
+        keys = [(bucket, q_dims.shape[1], q_dense.shape[1],
+                 engines[0].num_points, h_fetch[0], c1, c2,
+                 gen.shards is not None)]
+        if delta_engine is not None:
+            hd = delta_engine.num_points            # fetch every delta slot
+            cd1, cd2 = delta_engine.candidate_counts(hd, alpha, beta)
+            keys.append((bucket, q_dims.shape[1], q_dense.shape[1], hd, hd,
+                         cd1, cd2, "delta"))
         with self._lock:
-            c1, c2 = engines[0].candidate_counts(h_fetch[0], alpha, beta)
-            self._jit_keys.add((bucket, q_dims.shape[1], q_dense.shape[1],
-                                engines[0].num_points, h_fetch[0], c1, c2,
-                                gen.shards is not None))
-            if delta_engine is not None:
-                hd = delta_engine.num_points        # fetch every delta slot
-                cd1, cd2 = delta_engine.candidate_counts(hd, alpha, beta)
-                self._jit_keys.add((bucket, q_dims.shape[1],
-                                    q_dense.shape[1], hd, hd, cd1, cd2,
-                                    "delta"))
+            new = [k for k in keys if k not in self._jit_keys]
+            self._jit_keys.update(new)
+        if new:
+            # first use of this program by the service: a compile, unless
+            # another service on the same engine already ran it
+            self._c_new_programs.inc(len(new))
+            span.set("new_programs", len(new))
 
         # the shared fan-out merge (core/streaming.py::fanout_search — the
         # same helper search_mutable uses): dispatch every engine before
-        # syncing any, assemble in the common id space, merge on host.
-        timing = {} if span else None
-        out = fanout_search(engines, h_fetch, offsets, gen.id_map,
-                            delta_engine,
-                            view.ids if view is not None else None,
-                            deleted, qd, qv, qe, h=h, alpha=alpha,
-                            beta=beta, qn=qn, timing=timing)
-        if timing:
-            span.set("dispatch_s", timing["dispatch_s"])
-            span.set("merge_s", timing["merge_s"])
-        return out
+        # syncing any, assemble in the common id space, merge on host; it
+        # opens the serve.dispatch / device_wait / merge children of span.
+        return fanout_search(engines, h_fetch, offsets, gen.id_map,
+                             delta_engine,
+                             view.ids if view is not None else None,
+                             deleted, qd, qv, qe, h=h, alpha=alpha,
+                             beta=beta, qn=qn, span=span)
 
     # -- introspection ----------------------------------------------------
 

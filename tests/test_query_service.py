@@ -560,3 +560,97 @@ def test_metrics_exact_under_threaded_search(served):
     assert len(roots) == total
     assert sum(t["tags"]["qn"] for t in roots) == total
     assert sum(t["tags"]["cache_hits"] for t in roots) == info.hits
+
+
+def _children(span: dict, name: str) -> list[dict]:
+    return [c for c in span["children"] if c["name"] == name]
+
+
+@pytest.mark.parametrize("cache_size", [0, 64])
+def test_traced_search_names_each_stage(served, cache_size):
+    """With tracing on, a request's tree is serve.search -> serve.cache
+    (only with the cache on) and one serve.batch per chunk, each batch with
+    serve.pad / dispatch / device_wait / merge children in that order and
+    inside the batch's time; a batch new to the service is tagged with its
+    new programs."""
+    from repro.obs import Observability
+    _, idx, q_dims, q_vals, q_dense = served
+    svc = QueryService(idx.engine, h=5, buckets=(1, 4), cache_size=cache_size,
+                       obs=Observability(trace=True))
+    svc.search(q_dims[:6], q_vals[:6], q_dense[:6])
+    svc.search(q_dims[6:9], q_vals[6:9], q_dense[6:9])
+    first, second = svc.obs.tracer.take()
+    for root in (first, second):
+        assert root["name"] == "serve.search"
+        assert len(_children(root, "serve.cache")) == (1 if cache_size else 0)
+        assert "queue_s" not in root["tags"]
+    batches = _children(first, "serve.batch")
+    assert [b["tags"]["rows"] for b in batches] == [4, 2]
+    stages = ["serve.pad", "serve.dispatch", "serve.device_wait",
+              "serve.merge"]
+    for b in batches + _children(second, "serve.batch"):
+        assert [c["name"] for c in b["children"]] == stages
+        assert all(c["duration_s"] >= 0 for c in b["children"])
+        assert sum(c["duration_s"] for c in b["children"]) <= \
+            b["duration_s"]
+    # the first batch runs (bucket 4, params) first; the second search
+    # sends 3 rows to the same bucket
+    assert batches[0]["tags"]["new_programs"] == 1
+    assert "new_programs" not in _children(second, "serve.batch")[0]["tags"]
+
+
+def test_submit_stamps_queue_wait_and_counters_add_up(served):
+    """``submit`` tags each root with ``queue_s`` (queued -> a worker
+    entering ``search``); ``serve.rows`` counts the rows sent to the engine
+    and ``serve.bucket_rows`` the padded rows, never fewer; every first-seen
+    (bucket, params) program counts once in ``serve.new_programs``."""
+    from repro.obs import Observability
+    _, idx, q_dims, q_vals, q_dense = served
+    svc = QueryService(idx.engine, h=5, buckets=(1, 4, 8), cache_size=0,
+                       max_workers=1,
+                       obs=Observability(trace=True, keep_traces=64))
+    sizes = [1, 3, 5, 3, 8]
+    futs, lo = [], 0
+    for n in sizes:
+        rows = np.arange(lo, lo + n) % q_dims.shape[0]
+        futs.append(svc.submit(q_dims[rows], q_vals[rows], q_dense[rows]))
+        lo += n
+    for f in futs:
+        f.result(timeout=120)
+    svc.close()
+    roots = svc.obs.tracer.take()
+    assert len(roots) == len(sizes)
+    assert all(r["tags"]["queue_s"] >= 0 for r in roots)
+    snap = svc.metrics()
+    assert snap["serve.rows"] == sum(sizes)
+    assert snap["serve.bucket_rows"] == 1 + 4 + 8 + 4 + 8
+    assert snap["serve.bucket_rows"] >= snap["serve.rows"]
+    assert snap["serve.new_programs"] == 3 == \
+        svc.jit_cache_info().entries
+
+
+def test_spans_reach_the_profiler_timeline(served, tmp_path):
+    """Under ``jax.profiler.trace`` the service's context spans are host
+    events of the capture, nested inside the caller's own annotation."""
+    from jax.profiler import ProfileData, TraceAnnotation, trace
+    from repro.obs import Observability
+    _, idx, q_dims, q_vals, q_dense = served
+    svc = QueryService(idx.engine, h=5, cache_size=16,
+                       obs=Observability(trace=True))
+    svc.search(q_dims[:4], q_vals[:4], q_dense[:4])        # compile first
+    with trace(str(tmp_path)):
+        with TraceAnnotation("bench.search"):
+            svc.search(q_dims[4:8], q_vals[4:8], q_dense[4:8])
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    events = [e for p in ProfileData.from_file(str(path)).planes
+              for line in p.lines for e in line.events
+              if e.name.startswith(("bench.", "serve."))]
+    outer = [e for e in events if e.name == "bench.search"]
+    assert len(outer) == 1
+    lo, hi = outer[0].start_ns, outer[0].start_ns + outer[0].duration_ns
+    inner = {e.name for e in events
+             if e.name.startswith("serve.") and lo <= e.start_ns
+             and e.start_ns + e.duration_ns <= hi}
+    assert inner == {"serve.search", "serve.cache", "serve.batch",
+                     "serve.pad", "serve.dispatch", "serve.device_wait",
+                     "serve.merge"}
