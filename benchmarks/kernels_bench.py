@@ -53,25 +53,6 @@ def bench_lut16():
          f"traffic_reduction={dense_bytes / packed_bytes:.0f}x")
 
 
-def fused_vmem_bytes(bq: int, bn: int, k: int, *, l: int = 16,
-                     packed: bool = False, cbuf: int = 128) -> int:
-    """Resident VMEM estimate for one fused scan-and-select grid step
-    (DESIGN.md §2.5's budget table): code block (the whole stored K axis)
-    + chunked LUT block, times 2 for the double-buffered input stream
-    Pallas pipelines automatically, + one chunk's (128, bn) one-hot + the
-    score block + candidate buffers."""
-    kc = -(-k // 2) if packed else k
-    codes_blk = bn * kc                       # uint8
-    lut_blk = bq * -(-k // (128 // l)) * 128 * 4   # f32, chunk-padded
-    onehot = 128 * bn * 4                     # f32, one chunk
-    acc = bq * bn * 4                         # f32 score block
-    buf = bq * cbuf * (4 + 4)                 # f32 scores + i32 ids
-    return 2 * (codes_blk + lut_blk) + onehot + acc + buf
-
-
-VMEM_BUDGET = 16 * 2 ** 20                    # v5e per-core VMEM
-
-
 def autotune_fused_blocks(*, n: int = 8192, k: int = 32, l: int = 16,
                           q: int = 8, topk: int = 128,
                           packed: bool = False) -> dict:
@@ -81,7 +62,8 @@ def autotune_fused_blocks(*, n: int = 8192, k: int = 32, l: int = 16,
     BENCH_engine.json so the shipped defaults are an audited choice, not a
     guess.  Interpret-mode timings rank relative block overheads only; the
     VMEM feasibility column is hardware-independent."""
-    from repro.kernels.lut16 import candidate_buffer_width
+    from repro.kernels.lut16 import (VMEM_BUDGET, candidate_buffer_width,
+                                     vmem_bytes)
     from repro.kernels.ops import lut16_adc_topk
     rng = np.random.default_rng(7)
     codes_np = rng.integers(0, l, (n, k)).astype(np.uint8)
@@ -97,7 +79,8 @@ def autotune_fused_blocks(*, n: int = 8192, k: int = 32, l: int = 16,
     best = None
     for bq in (8,):
         for bn in (128, 256, 512, 1024):
-            vmem = fused_vmem_bytes(bq, bn, k, l=l, packed=packed, cbuf=cbuf)
+            vmem = vmem_bytes(bq, bn, -(-k // 2) if packed else k, l=l,
+                              packed=packed, cbuf=cbuf)
             entry = {"bq": bq, "bn": bn, "vmem_bytes": vmem,
                      "fits": vmem <= VMEM_BUDGET}
             if entry["fits"]:

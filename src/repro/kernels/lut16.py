@@ -17,6 +17,14 @@ codes fit bf16 — and ``rep == row % l`` is the one-hot, rows on sublanes and
 datapoints on lanes.  Packed nibbles are split in-register (``rep & 15`` and
 ``rep >> 4``), each contracted against its own half of the chunk's LUT.
 
+The one-hot is built in bf16 (0/1 is exact there) once per row block and
+contracted in ONE default-precision MXU pass against every LUT row of the
+query block.  For f32 compute the wrapper splits the f32 LUT into three
+bf16 terms, ``hi + mid + lo == lut`` exactly (``split_bf16x3``), stacked on
+the contraction's M axis: each product is exact and the MXU accumulates in
+f32, so the scores are f32 sums of the f32 LUT without a multi-pass f32
+emulation.  bfloat16 compute rounds the LUT to its one leading term.
+
 Two kernels share one accumulation body (``_block_partial``):
 
 * ``lut16_adc_pallas``      — materialize the full (Q, N) score matrix;
@@ -33,8 +41,10 @@ Contract (matches kernels/ref.py::lut16_adc_ref):
 Grid: (Q/bq, cdiv(N, bn)); a code block spans the whole stored K axis, so
 its lane dimension is always the full array width.  The last row block may
 be partial: its out-of-range rows are dropped (materialize) or masked to
--inf (select).  VMEM per step at K=100, bq=8, bn=512: 50 KiB codes + 52 KiB
-LUT + 256 KiB one-hot + 16 KiB scores, far under v5e's scoped 16 MiB.
+-inf (select).  ``vmem_bytes`` estimates one grid step; ops.py takes the
+whole batch as one query block (bq = Q) while that fits ``VMEM_BUDGET``.
+At K=384 packed, bq=32, bn=1024: 2.25 MiB of (96, 6144) bf16 LUT terms
+(×2 buffers), a 2 MiB one-hot working set, 6.4 MiB in all.
 """
 
 from __future__ import annotations
@@ -47,12 +57,19 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["lut16_adc_pallas", "lut16_adc_topk_pallas", "candidate_buffer_width",
-           "pack_codes", "unpack_codes", "default_interpret"]
+           "pack_codes", "unpack_codes", "default_interpret", "split_bf16x3",
+           "vmem_bytes", "VMEM_BUDGET"]
 
 # one-hot rows per MXU pass: the chunk of ``_CHUNK // l`` subspaces whose
 # one-hot fills one 128-deep contraction
 _CHUNK = 128
+# each LUT term's rows pad to a multiple of the f32 sublane tile, so the
+# term slices of the (terms * bqp, bn) accumulator start on tile boundaries
+_TERM_ROWS = 8
 _INT_MAX = jnp.iinfo(jnp.int32).max
+# the share of v5e's 16 MiB scoped VMEM one grid step may plan for; the
+# rest is left to Mosaic's own temporaries
+VMEM_BUDGET = 8 * 2 ** 20
 
 
 def default_interpret() -> bool:
@@ -94,30 +111,103 @@ def _lut_chunks(lut: jax.Array, kc: int, packed: bool):
     return x.reshape(q, g * _CHUNK), g
 
 
-def _block_partial(codes_ref, lut_ref, *, l: int, num_chunks: int,
-                   packed: bool, compute_dtype):
+def split_bf16x3(x: jax.Array):
+    """f32 -> (hi, mid, lo) bf16 with ``hi + mid + lo == x`` exactly.
+
+    Each term is the leading 8 significant bits of what the terms before it
+    left, cut by masking the f32 bit pattern: 3 × 8 bits cover the f32
+    significand's 24, and every term is exactly a bf16 value, so no rounding
+    happens that a compiler keeping excess precision could skip.  Exact for
+    |x| >= 2**-102, where the last term is still a normal number."""
+    def lead(v):
+        bits = jax.lax.bitcast_convert_type(v, jnp.uint32)
+        return jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
+                                            jnp.float32)
+    hi = lead(x)
+    rest = x - hi
+    mid = lead(rest)
+    return (hi.astype(jnp.bfloat16), mid.astype(jnp.bfloat16),
+            (rest - mid).astype(jnp.bfloat16))
+
+
+def _num_terms(compute_dtype) -> int:
+    if compute_dtype == jnp.float32:
+        return 3
+    if compute_dtype == jnp.bfloat16:
+        return 1
+    raise ValueError(f"compute_dtype must be float32 or bfloat16, "
+                     f"got {compute_dtype}")
+
+
+def _term_rows(bq: int) -> int:
+    return -(-bq // _TERM_ROWS) * _TERM_ROWS
+
+
+def _lut_terms(lut2: jax.Array, bq: int, compute_dtype):
+    """(Q, W) f32 chunk rows -> (Q/bq * T * bqp, W) bf16: per query block,
+    its T bf16 terms (``split_bf16x3`` for f32, the rounded LUT for bf16),
+    each zero-padded to bqp = ``_term_rows(bq)`` rows."""
+    terms = (split_bf16x3(lut2) if _num_terms(compute_dtype) == 3
+             else (lut2.astype(jnp.bfloat16),))
+    q, w = lut2.shape
+    bqp = _term_rows(bq)
+    x = jnp.stack(terms).reshape(len(terms), q // bq, bq, w)
+    x = jnp.pad(x, ((0, 0), (0, 0), (0, bqp - bq), (0, 0)))
+    return x.transpose(1, 0, 2, 3).reshape(-1, w)
+
+
+def _lut_width(kc: int, l: int, packed: bool) -> int:
+    """Lanes of one query's chunked LUT row (``_lut_chunks``)."""
+    g = -(-kc // (_CHUNK // l))
+    return g * (2 * _CHUNK if packed else _CHUNK)
+
+
+def vmem_bytes(bq: int, bn: int, kc: int, *, l: int, packed: bool,
+               cbuf: int, compute_dtype=jnp.float32) -> int:
+    """VMEM one grid step of either LUT16 kernel plans for (inputs and
+    outputs ×2 for Pallas' pipeline): the code block, the bf16 LUT terms,
+    the bias block, one chunk's selector/one-hot working set, the
+    (T * bqp, bn) accumulator with one matmul result, and the candidate
+    buffers with the merge loop's block-sized temporaries (cbuf=0: the
+    materialize kernel's (bq, bn) output)."""
+    rows = _num_terms(compute_dtype) * _term_rows(bq)
+    bqp = _term_rows(bq)
+    codes = 2 * bn * (-(-kc // 128) * 128)
+    lut = 2 * rows * _lut_width(kc, l, packed) * 2
+    bias = 2 * bqp * bn * 4
+    onehot = 4 * _CHUNK * bn * 4
+    acc = 2 * rows * bn * 4
+    select = 2 * 2 * bqp * cbuf * 4 + 4 * bqp * bn * 4
+    return codes + lut + bias + onehot + acc + select
+
+
+def _block_partial(codes_ref, lut_ref, *, bq: int, terms: int, l: int,
+                   num_chunks: int, packed: bool):
     """One (bq, bn) block of ``codes · lut`` on the MXU, chunk by chunk.
 
-    compute_dtype=float32 contracts at ``Precision.HIGHEST`` (true f32 on
-    the MXU); bfloat16 rounds the LUT and takes a single pass."""
-    bq = lut_ref.shape[0]
+    lut_ref holds the block's LUT as ``terms`` bf16 terms of bqp rows each
+    (``_lut_terms``).  Each chunk's bf16 one-hot is built once and
+    contracted in one DEFAULT-precision pass against all terms * bqp rows:
+    bf16 × {0, 1} products are exact and accumulate in f32.  The term sums
+    are added at the end, so f32 compute gives f32 sums of the f32 LUT."""
     codes = codes_ref[...].astype(jnp.int32).astype(jnp.float32)
     codes = codes.astype(jnp.bfloat16)                        # exact: < 256
     bn, kc = codes.shape
+    rows = lut_ref.shape[0]
+    bqp = rows // terms
     spc = _CHUNK // l
     shift = l.bit_length() - 1
     row = jax.lax.broadcasted_iota(jnp.int32, (_CHUNK, kc), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (_CHUNK, kc), 1)
     level = jax.lax.broadcasted_iota(jnp.int32, (_CHUNK, bn), 0) & (l - 1)
-    precision = (jax.lax.Precision.HIGHEST if compute_dtype == jnp.float32
-                 else jax.lax.Precision.DEFAULT)
     width = 2 * _CHUNK if packed else _CHUNK
 
     def lookup(lut_rows, nib):
-        onehot = (nib == level).astype(compute_dtype)         # (128, bn)
+        onehot = (nib == level).astype(jnp.bfloat16)          # (128, bn)
         return jax.lax.dot_general(
-            lut_rows.astype(compute_dtype), onehot, (((1,), (0,)), ((), ())),
-            precision=precision, preferred_element_type=jnp.float32)
+            lut_rows, onehot, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.DEFAULT,
+            preferred_element_type=jnp.float32)
 
     def chunk(g, acc):
         # selector (128, kc): one-hot row r copies code g*spc + r // l
@@ -132,8 +222,12 @@ def _block_partial(codes_ref, lut_ref, *, l: int, num_chunks: int,
                                 rep >> 4)
         return acc + lookup(lut_ref[:, pl.ds(start, _CHUNK)], rep)
 
-    return jax.lax.fori_loop(0, num_chunks, chunk,
-                             jnp.zeros((bq, bn), jnp.float32))
+    acc = jax.lax.fori_loop(0, num_chunks, chunk,
+                            jnp.zeros((rows, bn), jnp.float32))
+    total = acc[:bqp]
+    for t in range(1, terms):
+        total = total + acc[t * bqp:(t + 1) * bqp]
+    return total[:bq]
 
 
 def _kernel(codes_ref, lut_ref, out_ref, **kw):
@@ -158,7 +252,7 @@ _COMPILER_PARAMS = pltpu.CompilerParams(
                    static_argnames=("bq", "bn", "interpret", "compute_dtype",
                                     "packed"))
 def lut16_adc_pallas(codes: jax.Array, lut: jax.Array, *, bq: int = 8,
-                     bn: int = 512, interpret: bool,
+                     bn: int = 1024, interpret: bool,
                      compute_dtype=jnp.float32,
                      packed: bool = False) -> jax.Array:
     """Pallas LUT16 ADC.  codes: (N, K) uint8; lut: (Q, K, l) f32 -> (Q, N)
@@ -167,8 +261,8 @@ def lut16_adc_pallas(codes: jax.Array, lut: jax.Array, *, bq: int = 8,
     (``default_interpret()``).
 
     compute_dtype=bfloat16 rounds the LUT to bf16 (the paper's 8-bit
-    quantized LUT accuracy budget) for a single MXU pass; float32 keeps
-    full f32 products.
+    quantized LUT accuracy budget); float32 contracts the LUT's three exact
+    bf16 terms in the same single MXU pass, so the sums are of f32 values.
 
     packed=True: codes hold TWO 4-bit subspace codes per byte (shape
     (N, K/2); the paper's storage format) — HBM streams half the bytes and
@@ -176,19 +270,22 @@ def lut16_adc_pallas(codes: jax.Array, lut: jax.Array, *, bq: int = 8,
     n, kc, q, l = _check_shapes(codes, lut, packed)
     assert q % bq == 0, (q, bq)
     lut2, num_chunks = _lut_chunks(lut, kc, packed)
+    lut3 = _lut_terms(lut2, bq, compute_dtype)
+    terms = _num_terms(compute_dtype)
     return pl.pallas_call(
-        functools.partial(_kernel, l=l, num_chunks=num_chunks, packed=packed,
-                          compute_dtype=compute_dtype),
+        functools.partial(_kernel, bq=bq, terms=terms, l=l,
+                          num_chunks=num_chunks, packed=packed),
         grid=(q // bq, pl.cdiv(n, bn)),
         in_specs=[
             pl.BlockSpec((bn, kc), lambda iq, jn: (jn, 0)),
-            pl.BlockSpec((bq, lut2.shape[1]), lambda iq, jn: (iq, 0)),
+            pl.BlockSpec((terms * _term_rows(bq), lut3.shape[1]),
+                         lambda iq, jn: (iq, 0)),
         ],
         out_specs=pl.BlockSpec((bq, bn), lambda iq, jn: (iq, jn)),
         out_shape=jax.ShapeDtypeStruct((q, n), jnp.float32),
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-    )(codes, lut2)
+    )(codes, lut3)
 
 
 # ---------------------------------------------------------------------------
@@ -292,7 +389,7 @@ def _fused_kernel(codes_ref, lut_ref, base_ref, out_s_ref, out_i_ref, *,
                    static_argnames=("k", "bq", "bn", "interpret",
                                     "compute_dtype", "packed"))
 def lut16_adc_topk_pallas(codes: jax.Array, lut: jax.Array, base: jax.Array,
-                          *, k: int, bq: int = 8, bn: int = 512,
+                          *, k: int, bq: int = 8, bn: int = 1024,
                           interpret: bool, compute_dtype=jnp.float32,
                           packed: bool = False):
     """Fused LUT16 scan + top-k select (DESIGN.md §2.5).
@@ -316,15 +413,17 @@ def lut16_adc_topk_pallas(codes: jax.Array, lut: jax.Array, base: jax.Array,
     assert 0 < k <= n, (k, n)
     cbuf = candidate_buffer_width(k)
     lut2, num_chunks = _lut_chunks(lut, kc, packed)
+    lut3 = _lut_terms(lut2, bq, compute_dtype)
+    terms = _num_terms(compute_dtype)
     per_query = base.shape[0] != 1
     out_s, out_i = pl.pallas_call(
-        functools.partial(_fused_kernel, n=n, bn=bn, l=l,
-                          num_chunks=num_chunks, packed=packed,
-                          compute_dtype=compute_dtype),
+        functools.partial(_fused_kernel, n=n, bn=bn, bq=bq, terms=terms, l=l,
+                          num_chunks=num_chunks, packed=packed),
         grid=(q // bq, pl.cdiv(n, bn)),
         in_specs=[
             pl.BlockSpec((bn, kc), lambda iq, jn: (jn, 0)),
-            pl.BlockSpec((bq, lut2.shape[1]), lambda iq, jn: (iq, 0)),
+            pl.BlockSpec((terms * _term_rows(bq), lut3.shape[1]),
+                         lambda iq, jn: (iq, 0)),
             pl.BlockSpec((bq if per_query else 1, bn),
                          lambda iq, jn: (iq if per_query else 0, jn)),
         ],
@@ -336,7 +435,7 @@ def lut16_adc_topk_pallas(codes: jax.Array, lut: jax.Array, base: jax.Array,
                    jax.ShapeDtypeStruct((q, cbuf), jnp.int32)],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-    )(codes, lut2, base)
+    )(codes, lut3, base)
     return out_s, out_i
 
 

@@ -15,9 +15,10 @@ import numpy as np
 
 from .block_sparse import (block_sparse_matmul_pallas, dense_to_bcsr,
                            inverted_value_forward_pallas)
-from .lut16 import (candidate_buffer_width, default_interpret as _interpret,
-                    lut16_adc_pallas, lut16_adc_topk_pallas, pack_codes,
-                    unpack_codes)
+from .lut16 import (VMEM_BUDGET, candidate_buffer_width,
+                    default_interpret as _interpret, lut16_adc_pallas,
+                    lut16_adc_topk_pallas, pack_codes, unpack_codes,
+                    vmem_bytes)
 from .ref import lut16_adc_ref
 
 __all__ = ["lut16_adc", "lut16_adc_topk", "lut16_adc_onehot",
@@ -44,15 +45,35 @@ def _pad_to(x: np.ndarray | jax.Array, axis: int, mult: int, value=0):
     return jnp.pad(x, widths, constant_values=value), size
 
 
-def _resolve_lut16_blocks(q: int, n: int, bq: int, bn: int):
+def _resolve_lut16_blocks(q: int, n: int, kc: int, *, l: int, packed: bool,
+                          cbuf: int, bq: int | None, bn: int,
+                          compute_dtype=jnp.float32):
     """One block-size resolution for BOTH the materialize and the fused LUT16
     wrappers, so their per-block fp32 partial sums are bit-identical.
 
-    bn clamps against the 128-lane-rounded row count so small inputs get
-    one (partial) block instead of a full 512."""
-    bq = min(bq, max(1, q))
+    bq=None takes the whole batch as one query block, so each one-hot tile
+    is built and contracted once per row block for every query.  Only when
+    that would not fit ``VMEM_BUDGET`` (K, bn and cbuf set the step's
+    footprint, ``lut16.vmem_bytes``) is Q split into the fewest blocks of a
+    multiple of 8 rows that fit.  An explicit bq is kept (up to Q).  bn
+    clamps against the 128-lane-rounded row count so small inputs get one
+    (partial) block instead of a full one."""
+    q = max(1, q)
     bn = min(bn, max(-(-n // 128) * 128, 128))
-    return bq, bn
+    if bq is not None:
+        return min(bq, q), bn
+
+    def fits(b):
+        return vmem_bytes(b, bn, kc, l=l, packed=packed, cbuf=cbuf,
+                          compute_dtype=compute_dtype) <= VMEM_BUDGET
+
+    if fits(q):
+        return q, bn
+    cap = 8
+    while cap + 8 < q and fits(cap + 8):
+        cap += 8
+    per_block = -(-q // -(-q // cap))
+    return min(q, -(-per_block // 8) * 8), bn
 
 
 def _validate_packed(kc: int, k: int, l: int, lut: jax.Array,
@@ -71,12 +92,15 @@ def _validate_packed(kc: int, k: int, l: int, lut: jax.Array,
     return lut
 
 
-def lut16_adc(codes: jax.Array, lut: jax.Array, *, bq: int = 8, bn: int = 512,
-              compute_dtype=jnp.float32, packed: bool = False) -> jax.Array:
+def lut16_adc(codes: jax.Array, lut: jax.Array, *, bq: int | None = None,
+              bn: int = 1024, compute_dtype=jnp.float32,
+              packed: bool = False) -> jax.Array:
     """LUT16 ADC: codes (N, K) uint8, lut (Q, K, l) or (K, l) -> (Q, N).
 
     Pads Q to a block multiple and routes through the Pallas kernel (a
-    partial last row block needs no padding of the codes).
+    partial last row block needs no padding of the codes).  bq=None: the
+    whole batch in one query block while it fits VMEM
+    (``_resolve_lut16_blocks``).
 
     packed=True: codes hold TWO 4-bit subspace codes per byte, shape
     (N, ceil(K/2)) from pack_codes — HBM streams half the bytes; the kernel
@@ -89,7 +113,8 @@ def lut16_adc(codes: jax.Array, lut: jax.Array, *, bq: int = 8, bn: int = 512,
     q, k, l = lut.shape
     n, kc = codes.shape                 # kc: stored (byte) subspace axis
     lut = _validate_packed(kc, k, l, lut, packed)
-    bq, bn = _resolve_lut16_blocks(q, n, bq, bn)
+    bq, bn = _resolve_lut16_blocks(q, n, kc, l=l, packed=packed, cbuf=0,
+                                   bq=bq, bn=bn, compute_dtype=compute_dtype)
     lut_p, q0 = _pad_to(lut, 0, bq)
     out = lut16_adc_pallas(jnp.asarray(codes), lut_p, bq=bq, bn=bn,
                            interpret=_interpret(), compute_dtype=compute_dtype,
@@ -101,7 +126,7 @@ def lut16_adc(codes: jax.Array, lut: jax.Array, *, bq: int = 8, bn: int = 512,
 def lut16_adc_topk(codes: jax.Array, lut: jax.Array, k: int, *,
                    bias: jax.Array | None = None,
                    row_mask: jax.Array | None = None,
-                   bq: int = 8, bn: int = 512,
+                   bq: int | None = None, bn: int = 1024,
                    compute_dtype=jnp.float32, packed: bool = False,
                    fused: bool = True):
     """Pass-1 scan-and-select: top-k of ``bias + row_mask + codes·lut``
@@ -126,7 +151,9 @@ def lut16_adc_topk(codes: jax.Array, lut: jax.Array, k: int, *,
     if not 0 < k <= n:
         raise ValueError(f"top-k needs 0 < k <= N rows, got k={k}, N={n}")
     lut = _validate_packed(kc, kl, l, lut, packed)
-    bq, bn = _resolve_lut16_blocks(q, n, bq, bn)
+    bq, bn = _resolve_lut16_blocks(q, n, kc, l=l, packed=packed,
+                                   cbuf=candidate_buffer_width(k), bq=bq,
+                                   bn=bn, compute_dtype=compute_dtype)
 
     def _normalize(s, ids):
         return s, jnp.where(jnp.isfinite(s), ids, -1)

@@ -48,6 +48,85 @@ def test_lut16_compute_dtypes(dtype):
     np.testing.assert_allclose(got, want, rtol=tol, atol=tol * 10)
 
 
+def test_split_bf16x3_is_exact():
+    """The f32 LUT's three bf16 terms add back to it bit for bit: random
+    LUTs of both signs over many scales, and the extremes that keep the
+    last term a normal number (down to 1e-30, up to the largest f32)."""
+    from repro.kernels.lut16 import split_bf16x3
+    f32 = np.finfo(np.float32)
+    x = (RNG.normal(size=(4, 100, 16))
+         * 10.0 ** RNG.integers(-25, 30, (4, 100, 1))).astype(np.float32)
+    extremes = np.array([f32.max, -f32.max, 1e-30, -1e-30, 2.0 ** -100,
+                         np.nextafter(np.float32(1), np.float32(2)), -1.0,
+                         0.0, 3.0e38, np.float32(np.pi)], np.float32)
+    for v in (x, extremes):
+        terms = split_bf16x3(jnp.asarray(v))
+        assert all(t.dtype == jnp.bfloat16 for t in terms)
+        hi, mid, lo = (np.asarray(t, np.float32) for t in terms)
+        np.testing.assert_array_equal((hi + mid) + lo, v)
+        np.testing.assert_array_equal(hi + (mid + lo), v)
+    assert (np.asarray(split_bf16x3(jnp.asarray(x))[0], np.float32)
+            != x).mean() > 0.9                 # one bf16 term is not enough
+
+
+@pytest.mark.parametrize("k", [100, 384])
+@pytest.mark.parametrize("packed", [False, True], ids=["unpacked", "packed"])
+def test_lut16_f32_matches_float64(k, packed):
+    """compute_dtype=float32 keeps f32: every score is within 1e-6 of the
+    float64 sum, relative to the sum of |terms| (a score whose terms cancel
+    keeps no relative digits below that scale), at QuerySim's K=100 and the
+    passage embeddings' K=384.  The bfloat16 path stays measurably coarser."""
+    from repro.kernels.lut16 import pack_codes
+    n, q = 1000, 3
+    codes = RNG.integers(0, 16, (n, k)).astype(np.uint8)
+    lut = RNG.normal(size=(q, k, 16)).astype(np.float32)
+    terms = np.take_along_axis(
+        lut[:, None].astype(np.float64),
+        codes[None, :, :, None].astype(np.int64), axis=3)[..., 0]
+    want, scale = terms.sum(-1), np.abs(terms).sum(-1)
+    codes_in = jnp.asarray(pack_codes(codes) if packed else codes)
+    err = {}
+    for dt in (jnp.float32, jnp.bfloat16):
+        got = np.asarray(lut16_adc(codes_in, jnp.asarray(lut), packed=packed,
+                                   compute_dtype=dt), np.float64)
+        err[dt] = np.max(np.abs(got - want) / scale)
+    assert err[jnp.float32] <= 1e-6, err
+    assert err[jnp.bfloat16] > 1e-4, err
+
+
+@pytest.mark.parametrize("k", [100, 384])
+def test_resolve_lut16_blocks_takes_the_whole_batch(k):
+    """bq follows Q: the service's 1-, 8- and 32-query buckets each run as
+    one query block at K=100 and K=384 packed; an explicit bq is kept; a
+    batch past the VMEM budget splits into the fewest blocks of a multiple
+    of 8 rows that fit."""
+    from repro.kernels.lut16 import VMEM_BUDGET, vmem_bytes
+    from repro.kernels.ops import _resolve_lut16_blocks
+    kc, n, bn = k // 2, 1_000_000, 1024
+    kw = dict(l=16, packed=True, cbuf=256)
+    for q in (1, 8, 32):
+        assert _resolve_lut16_blocks(q, n, kc, bq=None, bn=bn, **kw) == (q, bn)
+    assert _resolve_lut16_blocks(32, n, kc, bq=8, bn=bn, **kw) == (8, bn)
+    assert _resolve_lut16_blocks(5, n, kc, bq=8, bn=bn, **kw) == (5, bn)
+    assert _resolve_lut16_blocks(32, 300, kc, bq=None, bn=bn, **kw) == (32, 384)
+    bq, bn = _resolve_lut16_blocks(4096, n, kc, bq=None, bn=bn, **kw)
+    assert bq % 8 == 0 and 8 <= bq < 4096
+    assert vmem_bytes(bq, bn, kc, **kw) <= VMEM_BUDGET
+    assert -(-4096 // bq) * bq - 4096 < 8 * -(-4096 // bq)    # little padding
+
+
+def test_lut16_explicit_query_block_matches_whole_batch():
+    """Q=32 split into four 8-query blocks (24-row bf16 LUT blocks) scores
+    exactly like the one 32-query block the default takes."""
+    from repro.kernels.lut16 import pack_codes
+    codes = jnp.asarray(pack_codes(RNG.integers(0, 16, (700, 20))
+                                   .astype(np.uint8)))
+    lut = jnp.asarray(RNG.normal(size=(32, 20, 16)).astype(np.float32))
+    whole = lut16_adc(codes, lut, packed=True)
+    split = lut16_adc(codes, lut, packed=True, bq=8)
+    np.testing.assert_array_equal(np.asarray(split), np.asarray(whole))
+
+
 def test_lut16_padding_wrapper():
     """Non-multiple shapes go through ops.lut16_adc padding."""
     codes = jnp.asarray(RNG.integers(0, 16, (777, 13)).astype(np.uint8))
@@ -209,6 +288,7 @@ def _topk_oracle(scores: np.ndarray, k: int):
     (4000, 7, 16, 12),     # odd K (phantom nibble when packed)
     (300, 5, 8, 3),        # small l, tiny N
     (2048, 16, 16, 8),     # exact-multiple shapes
+    (1000, 8, 16, 32),     # the 32-query bucket: one block, bq = 32
 ])
 @pytest.mark.parametrize("packed", [False, True])
 @pytest.mark.parametrize("topk", [5, 37, 128])
