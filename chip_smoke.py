@@ -1,10 +1,9 @@
 #!/usr/bin/env python3
 """Chip smoke test: the three-pass hybrid search end to end on a TPU.
 
-    python3 chip_smoke.py                 # one chip (the default)
-    python3 chip_smoke.py --four-chips    # the sharded fan-out on 4 chips
+    python3 chip_smoke.py
 
-One chip: QuerySim-shaped data (paper Table 3: power-law sparse activity,
+QuerySim-shaped data (paper Table 3: power-law sparse activity,
 134 nnz/row nominal, 200 dense dims, K=100 PQ subspaces) at 1,000,000 rows,
 generated from ``--seed``; ``HybridIndex.build`` with the ``pallas-packed``
 backend; ``QueryService`` serving warmed batches of 1, 8 and 32 queries; a
@@ -14,10 +13,8 @@ backend on the same ``IndexArrays``, recall@10 against exact float32
 search matches ``ref``'s, the inserted planted row is found and the deleted
 row never returns.
 
-Four chips: the paper's fan-out (every shard refines its own rows, the
-coordinator merges, §7.2) through ``make_sharded_search3_fn`` over a
-("data", 4) mesh, with each chip loading only its rows, compared with the
-same per-shard search run shard by shard on one chip and merged on the host.
+The sharded deployment on four chips is the benchmark's
+``querysim-4m.fanout4`` cell (``bench/run.py``).
 
 Every time printed is a smoke timing on the host's wall clock, not a device
 metric.  Any failed check exits non-zero; the last line of a passing run is
@@ -40,7 +37,6 @@ BUCKETS = (1, 8, 32)
 NUM_QUERIES = 64
 PAPER_ROWS = 5_000_000               # paper Table 3 corpus
 ONE_CHIP_ROWS = 1_000_000            # one chip's shard of it
-FOUR_CHIP_ROWS = 200_000             # the fan-out check, not a sized cell
 
 
 def log(msg: str) -> None:
@@ -69,10 +65,10 @@ def make_data(rows: int, seed: int):
                                seed=seed)
 
 
-def index_params(**kw):
+def index_params():
     from repro.core.hybrid import HybridIndexParams
     return HybridIndexParams(keep_top=192, head_dims=128, kmeans_iters=6,
-                             backend="pallas-packed", **kw)
+                             backend="pallas-packed")
 
 
 def exact_topk(ds, h: int):
@@ -186,96 +182,12 @@ def run_one_chip(rows: int, seed: int) -> None:
     svc.close()
 
 
-def run_four_chips(rows: int, seed: int) -> None:
-    import jax
-    import numpy as np
-
-    from repro.core.distributed import (merge_topk_host,
-                                        sharded_three_pass_topk,
-                                        split_index_arrays)
-    from repro.core.engine import scatter_queries_compact
-    from repro.core.hybrid import HybridIndex
-    from repro.core.pq import adc_lut
-    from repro.core.sparse_index import sparse_queries_to_padded
-    from repro.launch.mesh import make_test_mesh
-
-    walls: dict[str, float] = {}
-    shards = 4
-    check(len(jax.devices()) >= shards, f"{shards} TPU chips are visible")
-    log(f"rows: {rows:,} ({rows // shards:,} per chip); a fan-out check, "
-        f"not a sized cell")
-    # the index is built on the host's CPU backend, so the only copies on
-    # the chips are the per-chip row shards placed below
-    with phase("data_build_host", walls), \
-            jax.default_device(jax.devices("cpu")[0]):
-        ds = make_data(rows, seed)
-        params = index_params(use_head_block=False)
-        idx = HybridIndex.build(ds.x_sparse, ds.x_dense, params)
-        arrays = idx.engine.arrays
-        parts, offsets = split_index_arrays(arrays, shards)
-        q_dims, q_vals = sparse_queries_to_padded(ds.q_sparse, idx.cols,
-                                                  nq_max=params.nq_max)
-        q_dense = np.asarray(ds.q_dense, np.float32)
-        lut = np.asarray(adc_lut(q_dense, arrays.codebooks))
-        q_cols = np.asarray(scatter_queries_compact(q_dims, q_vals,
-                                                    arrays.d_active))
-    host = np.asarray
-    full = dict(codes=host(arrays.codes), res_q=host(arrays.dense_residual.q),
-                sres_cols=host(arrays.sparse_residual.cols),
-                sres_vals=host(arrays.sparse_residual.vals))
-    scale = host(arrays.dense_residual.scale)
-    zero = host(arrays.dense_residual.zero)
-    inv = [(host(p.inv_index.rows), host(p.inv_index.vals)) for p in parts]
-    index_bytes = (sum(v.nbytes for v in full.values())
-                   + sum(r.nbytes + v.nbytes for r, v in inv))
-    queries = (q_dims, q_vals, q_dense, q_cols)
-    kw = dict(h=H, alpha=ALPHA, beta=BETA, adc="pallas-packed")
-
-    def search(mesh, rows_sl, inv_part):
-        return sharded_three_pass_topk(
-            mesh, full["codes"][rows_sl], lut, *inv_part,
-            full["res_q"][rows_sl], scale, zero, full["sres_cols"][rows_sl],
-            full["sres_vals"][rows_sl], *queries, **kw)
-
-    with phase("sharded_search", walls):
-        mesh = make_test_mesh((shards,), ("data",))
-        s4, i4 = search(mesh, slice(None), tuple(
-            np.concatenate(x) for x in zip(*inv)))
-        s4, i4 = np.asarray(s4), np.asarray(i4)
-    # the peak so far covers placement and the whole sharded search
-    per_chip = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-                for d in jax.devices()]
-    log(f"index bytes (all shards): {index_bytes:,}; peak bytes in use per "
-        f"chip: {per_chip}")
-    check(max(per_chip) < index_bytes, "no chip ever held the whole index")
-
-    with phase("shard_by_shard_one_chip", walls):
-        mesh1 = make_test_mesh((1,), ("data",))
-        bounds = list(offsets) + [rows]
-        merged = []
-        for s in range(shards):
-            lo, hi = int(bounds[s]), int(bounds[s + 1])
-            ss, ii = search(mesh1, slice(lo, hi), inv[s])
-            merged.append((np.asarray(ss), np.asarray(ii) + lo, True))
-        s1, i1 = merge_topk_host(merged, H)
-    check(np.array_equal(i4, i1),
-          "4-chip ids equal the shard-by-shard one-chip merge exactly")
-    check(np.allclose(s4, s1, rtol=1e-5, atol=1e-5),
-          "4-chip scores match to f32 tolerance "
-          f"(max |diff| {float(np.max(np.abs(s4 - s1))):.3g})")
-    log("phase walls (s, smoke timing): " + json.dumps(
-        {k: round(v, 3) for k, v in walls.items()}))
-
-
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    ap.add_argument("--four-chips", action="store_true",
-                    help="run only the sharded fan-out check on 4 chips")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
-    # JAX must find the TPU or fail: no silent CPU run.  The four-chip
-    # check also keeps the CPU backend, to build its index off the chips.
-    os.environ["JAX_PLATFORMS"] = "tpu,cpu" if args.four_chips else "tpu"
+    # JAX must find the TPU or fail: no silent CPU run
+    os.environ["JAX_PLATFORMS"] = "tpu"
     sys.path.insert(0, os.path.join(ROOT, "src"))
     import jax
 
@@ -287,10 +199,7 @@ def main() -> int:
         raise SystemExit(f"no TPU: JAX's first device is {dev.platform!r}")
     log(f"device: {dev.device_kind} x{len(jax.devices())}; jax "
         f"{jax.__version__}")
-    if args.four_chips:
-        run_four_chips(FOUR_CHIP_ROWS, args.seed)
-    else:
-        run_one_chip(ONE_CHIP_ROWS, args.seed)
+    run_one_chip(ONE_CHIP_ROWS, args.seed)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(jax.devices())}}), flush=True)

@@ -375,7 +375,10 @@ class MutableState:
         # frozen head-dim set (compact ids, pad -1): merge_compact rebuilds
         # the head block over the SAME dims instead of re-ranking activity,
         # so its tile layout stays comparable across merge generations
-        self.head_dims0 = np.asarray(index.head_dim_ids)
+        # a sharded index's per-shard artifacts stay with its shards; the
+        # delta shard, on device 0, encodes inserts against shard 0's
+        base = index.shards[0] if index.shards is not None else index
+        self.head_dims0 = np.asarray(base.head_dim_ids)
         # sparse entries silently outside the frozen column space in the
         # merged MAIN structures (merge_compact carries + grows this);
         # nonzero means only a retrain can make them searchable
@@ -390,8 +393,8 @@ class MutableState:
         self._loc = {int(e): ("init", i)
                      for i, e in enumerate(self.ids_built)}
         self.delta = DeltaShard(
-            codebooks=index.codebooks, cols=index.cols,
-            dense_residual=index.dense_residual, d_dense=index.d_dense,
+            codebooks=base.codebooks, cols=index.cols,
+            dense_residual=base.dense_residual, d_dense=index.d_dense,
             pack=index.params.resolve_pack(), capacity=delta_capacity)
 
     # -- mutation ---------------------------------------------------------
@@ -521,6 +524,10 @@ class MutableState:
                                    build_padded_rows, build_tile_sparse_head)
         if self.live_rows == 0:
             raise ValueError(self._EMPTY_COMPACT_MSG)
+        if self.params.num_shards > 1:
+            raise ValueError("merge compaction keeps one shard's frozen "
+                             "artifacts; a sharded index compacts by "
+                             "rebuild (retrain=True)")
         params, delta = self.params, self.delta
         cols, codebooks = delta.cols, delta.codebooks
         xs, xd, ids = self.survivors()
@@ -576,28 +583,31 @@ class MutableState:
         new.mutable_state.main_dropped_nnz = self.main_dropped_nnz + dropped
         return new
 
-    def compact(self, retrain: bool | None = None):
+    def compact(self, retrain: bool | None = None, obs=None):
         """Fold delta + tombstones down; returns a NEW mutable
         ``HybridIndex`` (this state is untouched; the caller swaps, e.g.
         through QueryService's double-buffered ``refresh()``).
 
         ``retrain=True`` re-runs the full batch build on the surviving rows
         (new codebooks, new compact column space, new cache-sort) —
-        bit-identical to building from scratch.  ``retrain=False`` merges
-        into the frozen artifacts (``merge_compact``).  The default
-        ``None`` auto-routes: merge, unless sparse entries have been
-        dropped outside the frozen column space (delta buffering or a
-        previous merge) — those only become searchable under a retrain."""
+        bit-identical to building from scratch, with the same
+        ``num_shards``.  ``retrain=False`` merges into the frozen artifacts
+        (``merge_compact``; unsharded indexes only).  The default ``None``
+        auto-routes: merge, unless the index is sharded or sparse entries
+        have been dropped outside the frozen column space (delta buffering
+        or a previous merge) — those only become searchable under a
+        retrain.  ``obs`` gets the rebuild's ``index.build`` span."""
         from .hybrid import HybridIndex
         if self.live_rows == 0:
             raise ValueError(self._EMPTY_COMPACT_MSG)
         if retrain is None:
-            retrain = (self.delta.dropped_nnz + self.main_dropped_nnz) > 0
+            retrain = (self.params.num_shards > 1 or
+                       self.delta.dropped_nnz + self.main_dropped_nnz > 0)
         if not retrain:
             return self.merge_compact()
         xs, xd, ids = self.survivors()
         new = HybridIndex.build(xs, xd, self.params, mutable=True,
-                                ext_ids=ids)
+                                ext_ids=ids, obs=obs)
         # carry the id counter: the fresh state only sees surviving ids, so
         # recomputing max+1 could re-mint a previously deleted id and
         # resurrect it under new content
@@ -704,10 +714,11 @@ def fanout_search(engines, h_fetch, offsets, id_map, delta_engine,
 
 def search_mutable(index, q_sparse, q_dense, h: int = 20,
                    alpha: int | None = None, beta: int | None = None):
-    """Three-pass search over main generation + delta shard with host merge
-    (DESIGN.md §6.2) — the single-process form of what QueryService does in
-    its fan-out (literally the same ``fanout_search`` helper).  Returns a
-    SearchResult whose ids are EXTERNAL ids."""
+    """Three-pass search over the main generation's shard engines + delta
+    shard with host merge (DESIGN.md §6.2) — the single-process form of
+    what QueryService does in its fan-out (literally the same
+    ``fanout_search`` helper).  Returns a SearchResult whose ids are
+    EXTERNAL ids."""
     from .hybrid import SearchResult
     from .sparse_index import sparse_queries_to_padded
 
@@ -720,14 +731,15 @@ def search_mutable(index, q_sparse, q_dense, h: int = 20,
     qd, qv = jnp.asarray(q_dims), jnp.asarray(q_vals)
     qe = jnp.asarray(np.asarray(q_dense, np.float32))
 
-    h_fetch = plan_overfetch([index.engine], h, st.main_tombstones)
+    engines = index.engines
+    h_fetch = plan_overfetch(engines, h, st.main_tombstones)
     snap = st.delta.snapshot() if st.delta.live_count else None
     delta_engine = None
     if snap is not None:
         delta_engine = ScoringEngine(arrays=snap.arrays,
                                      backend=index.engine.backend)
     s, ids = fanout_search(
-        [index.engine], h_fetch, np.zeros(1, np.int64),
+        engines, h_fetch, index.row_offsets,
         st.id_map, delta_engine,
         snap.ids if snap is not None else None, st.main_tombstones,
         qd, qv, qe, h=h, alpha=alpha, beta=beta)

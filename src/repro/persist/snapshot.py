@@ -175,6 +175,9 @@ def write_snapshot(root: str, index, *, replay_from_seq: int,
     if st is None:
         raise ValueError("snapshots need a mutable index "
                          "(HybridIndex.build(..., mutable=True))")
+    if index.shards is not None:
+        raise ValueError("a row-sharded index has no snapshot format; "
+                         "persist it unsharded")
     if not delta_state and (st.version != 0 or st.delta.count
                             or st.main_tombstones):
         raise ValueError(

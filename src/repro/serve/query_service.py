@@ -18,9 +18,11 @@ and "refined top-h ids come back":
   generation), with exact hit/miss/eviction counters (``cache_info()``).
   Repeats in a warm stream never touch the device;
 
-* **async shard fan-out** — with ``num_shards > 1`` the index is row-sliced
-  once (``core.distributed.split_index_arrays``) into per-shard engines;
-  a request dispatches the FULL three-pass search on every shard
+* **async shard fan-out** — a row-sharded index (``HybridIndexParams.
+  num_shards``, one engine per shard, each on its own device) serves its
+  shard engines; otherwise ``num_shards > 1`` row-slices one engine
+  (``core.distributed.split_index_arrays``) into per-shard engines on its
+  device.  A request dispatches the FULL three-pass search on every shard
   back-to-back (JAX async dispatch overlaps them — the in-process analogue
   of the paper's RPC fan-out) and the per-shard top-h sets are merged on the
   host, the same merge the ``shard_map`` path does with ``all_gather``;
@@ -70,6 +72,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.distributed import split_index_arrays
+from repro.core.hybrid import ShardEngines
 from repro.core.engine import (Backend, IndexArrays, ScoringEngine,
                                query_fingerprint, release_index_arrays)
 from repro.core.sparse_index import sparse_queries_to_padded
@@ -132,10 +135,11 @@ class JitCacheInfo:
 
 @dataclasses.dataclass(eq=False)
 class _Generation:
-    """One installed index copy: the single-device engine, optional per-shard
+    """One installed index copy: the single-device engine (None for a
+    row-sharded index, which has no one engine), optional per-shard
     engines, and the refcount that gates donation of retired buffers.
     eq=False: identity semantics for the service's generation registry."""
-    engine: ScoringEngine
+    engine: ScoringEngine | None
     shards: list[ScoringEngine] | None
     offsets: np.ndarray | None
     id_map: np.ndarray | None
@@ -143,6 +147,22 @@ class _Generation:
     refs: int = 0
     retired: bool = False
     donate: bool = True
+
+    @property
+    def engines(self) -> list[ScoringEngine]:
+        """The engines a search fans out over, in row order."""
+        return self.shards if self.shards is not None else [self.engine]
+
+    @property
+    def num_points(self) -> int:
+        return sum(e.num_points for e in self.engines)
+
+
+def _refuse_shard_engines(engine) -> None:
+    if isinstance(engine, ShardEngines):
+        raise ValueError("a row-sharded index's engine answers only as all "
+                         "its shards merged: serve it with "
+                         "QueryService(index=)")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -181,7 +201,9 @@ class QueryService:
         LRU result-cache capacity in query rows (0 disables caching).
     num_shards:
         Row-shard the index into this many per-shard engines and fan out
-        (requires ``num_points % num_shards == 0``).
+        (requires ``num_points % num_shards == 0``).  An ``index=`` built
+        with ``HybridIndexParams.num_shards > 1`` brings its own shard
+        engines, one per device, and sets this.
     id_map:
         Optional position -> external id mapping (``HybridIndex.pi``)
         applied to returned ids.
@@ -237,7 +259,12 @@ class QueryService:
         came through ``submit``) with ``serve.cache`` and ``serve.batch``
         children, each batch with ``serve.pad``, ``serve.dispatch``,
         ``serve.device_wait`` and ``serve.merge`` children (DESIGN.md
-        §9.2).  ``Observability.off()`` nulls everything — the no-obs
+        §9.2); ``serve.batch`` carries ``shards``, the main engines it
+        fans out over.  Counter ``serve.shard_dispatches`` counts engine
+        searches dispatched (main shards and a live delta), gauge
+        ``index.shards`` the serving generation's main engines, and a
+        compaction's rebuild reports its ``index.build`` span here.
+        ``Observability.off()`` nulls everything — the no-obs
         baseline for overhead measurement (§9.4).
     """
 
@@ -271,6 +298,8 @@ class QueryService:
         self._c_rows = m.counter("serve.rows")
         self._c_bucket_rows = m.counter("serve.bucket_rows")
         self._c_new_programs = m.counter("serve.new_programs")
+        self._c_shard_dispatches = m.counter("serve.shard_dispatches")
+        self._g_shards = m.gauge("index.shards")
         self._h_compact = m.histogram("serve.compact_s")
         self._g_delta = m.gauge("serve.delta_rows")
         self._durability = None
@@ -297,11 +326,24 @@ class QueryService:
             if index.mutable_state is None:
                 raise ValueError("index= needs HybridIndex.build(..., "
                                  "mutable=True)")
-            if engine is None:
-                engine = index.engine
             if id_map is None:
                 id_map = index.mutable_state.id_map
-        if engine is None:
+            if index.shards is not None:
+                if engine is not None:
+                    raise ValueError("a row-sharded index= brings its own "
+                                     "shard engines; pass no engine=")
+                if num_shards not in (1, len(index.shards)):
+                    raise ValueError(
+                        f"index= has {len(index.shards)} row shards; "
+                        f"num_shards={num_shards} disagrees")
+                if compact_retrain is False:
+                    raise ValueError("a sharded index compacts by rebuild; "
+                                     "compact_retrain=False cannot apply")
+                num_shards = len(index.shards)
+            elif engine is None:
+                engine = index.engine
+        _refuse_shard_engines(engine)
+        if engine is None and (index is None or index.shards is None):
             if arrays is None:
                 raise ValueError("pass an engine, arrays, or a mutable index")
             engine = ScoringEngine(arrays=arrays,
@@ -315,13 +357,16 @@ class QueryService:
         self._version = 0
         self._next_version = 0      # monotonic; unique even across races
         self._gens: set[_Generation] = set()   # every not-yet-donated copy
-        self._gen = self._make_generation(engine, id_map, self._version)
+        self._gen = self._make_generation(engine, id_map, self._version,
+                                          index)
+        self._g_shards.set(len(self._gen.engines))
         self._cache: OrderedDict[str, tuple[np.ndarray, np.ndarray]] = \
             OrderedDict()
         self._cache_cap = cache_size
         self._jit_keys: set[tuple] = set()
         self._executor: ThreadPoolExecutor | None = None
         self._max_workers = max_workers
+        self._compile_pool: ThreadPoolExecutor | None = None
         # streaming mutation state (all guarded by _mut_lock except the
         # view/version, which searches read under _lock)
         self._index = index
@@ -346,9 +391,12 @@ class QueryService:
 
     def _make_generation(self, engine: ScoringEngine,
                          id_map: np.ndarray | None,
-                         version: int) -> _Generation:
+                         version: int, index=None) -> _Generation:
         shards = offsets = None
-        if self.num_shards > 1:
+        if index is not None and index.shards is not None:
+            # a row-sharded index's generation has no one engine
+            engine, shards, offsets = None, index.engines, index.offsets
+        elif self.num_shards > 1:
             parts, offsets = split_index_arrays(engine.arrays,
                                                 self.num_shards)
             shards = [ScoringEngine(arrays=a, backend=engine.backend)
@@ -399,14 +447,16 @@ class QueryService:
             for g in self._gens:
                 if g is gen:
                     continue
-                keep.append(g.engine.arrays)
+                if g.engine is not None:
+                    keep.append(g.engine.arrays)
                 if g.shards is not None:
                     keep += [s.arrays for s in g.shards]
             if gen.donate:
                 self._gens.discard(gen)
         if not gen.donate:
             return
-        release_index_arrays(gen.engine.arrays, keep=keep)
+        if gen.engine is not None:
+            release_index_arrays(gen.engine.arrays, keep=keep)
         if gen.shards is not None:
             for s in gen.shards:
                 release_index_arrays(s.arrays, keep=keep)
@@ -438,6 +488,7 @@ class QueryService:
             backend = self._gen.engine.backend
             self._next_version += 1
             version = self._next_version
+        _refuse_shard_engines(arrays)
         if isinstance(arrays, ScoringEngine):
             engine = arrays
         else:
@@ -454,9 +505,11 @@ class QueryService:
             self._gen = new
             self._version = new.version
             self._c_refreshes.inc()
+            self._g_shards.set(len(new.engines))
             old.retired = True
-            old.donate = donate and \
-                old.engine.arrays is not new.engine.arrays
+            old.donate = donate and (
+                old.engine is None or new.engine is None
+                or old.engine.arrays is not new.engine.arrays)
             if on_swap is not None:
                 on_swap()
             dead = old.refs == 0
@@ -585,7 +638,7 @@ class QueryService:
             return False        # batch build needs >= 1 surviving row
         pending = st.delta.live_count + len(st.main_tombstones)
         floor = max(self._compact_min_rows,
-                    int(self._compact_ratio * self._gen.engine.num_points))
+                    int(self._compact_ratio * self._gen.num_points))
         return pending >= floor
 
     def _spawn_compaction(self) -> None:
@@ -635,14 +688,14 @@ class QueryService:
             if st.delta.count == 0 and not st.main_tombstones:
                 return self.version              # nothing to fold
             # heavy; off serving lock
-            new_idx = self._index.compact(retrain=retrain)
+            new_idx = self._index.compact(retrain=retrain, obs=self.obs)
             new_state = new_idx.mutable_state
             engine = new_idx.engine
             with self._lock:
                 self._next_version += 1
                 version = self._next_version
             new_gen = self._make_generation(engine, new_state.id_map,
-                                            version)
+                                            version, new_idx)
 
             def on_swap():
                 self._index = new_idx
@@ -767,8 +820,8 @@ class QueryService:
             for lo in range(0, len(miss), max_bucket):
                 rows = miss[lo:lo + max_bucket]
                 with sp.child("serve.batch", rows=len(rows),
-                              bucket=bucket_for(len(rows),
-                                                self.buckets)) as bs:
+                              bucket=bucket_for(len(rows), self.buckets),
+                              shards=len(gen.engines)) as bs:
                     s, ids = self._run_batch(gen, view, q_dims[rows],
                                              q_vals[rows], q_dense[rows],
                                              h, alpha, beta, span=bs)
@@ -827,20 +880,22 @@ class QueryService:
         very same bits as before."""
         qn = q_dims.shape[0]
         bucket = bucket_for(qn, self.buckets)
-        d_active = gen.engine.arrays.d_active
+        d_active = gen.engines[0].arrays.d_active
         with span.child("serve.pad"):
             qd = jnp.asarray(pad_rows(q_dims, bucket, fill=d_active))
             qv = jnp.asarray(pad_rows(q_vals, bucket))
             qe = jnp.asarray(pad_rows(q_dense, bucket))
 
         deleted = view.deleted if view is not None else frozenset()
-        engines = gen.shards if gen.shards is not None else [gen.engine]
+        engines = gen.engines
         offsets = (gen.offsets if gen.shards is not None
                    else np.zeros(1, np.int64))
         h_fetch = plan_overfetch(engines, h, deleted)
         delta_engine = view.engine if view is not None else None
 
         self._c_batches.inc()
+        self._c_shard_dispatches.inc(len(engines)
+                                     + (delta_engine is not None))
         self._c_rows.inc(qn)
         self._c_bucket_rows.inc(bucket)
         c1, c2 = engines[0].candidate_counts(h_fetch[0], alpha, beta)
@@ -861,6 +916,18 @@ class QueryService:
             self._c_new_programs.inc(len(new))
             span.set("new_programs", len(new))
 
+        # a new program compiles once per device its engines sit on: where
+        # they sit on several, its first use dispatches from threads, so
+        # the compiles overlap instead of queueing in the dispatch loop
+        executor = None
+        if new and len({d for e in engines
+                        for d in e.arrays.codes.devices()}) > 1:
+            with self._lock:
+                if self._compile_pool is None:
+                    self._compile_pool = ThreadPoolExecutor(
+                        thread_name_prefix="query-service-compile")
+                executor = self._compile_pool
+
         # the shared fan-out merge (core/streaming.py::fanout_search — the
         # same helper search_mutable uses): dispatch every engine before
         # syncing any, assemble in the common id space, merge on host; it
@@ -869,7 +936,7 @@ class QueryService:
                              delta_engine,
                              view.ids if view is not None else None,
                              deleted, qd, qv, qe, h=h, alpha=alpha,
-                             beta=beta, qn=qn, span=span)
+                             beta=beta, qn=qn, executor=executor, span=span)
 
     # -- introspection ----------------------------------------------------
 
@@ -949,10 +1016,12 @@ class QueryService:
         start after close() returns."""
         with self._lock:
             ex, self._executor = self._executor, None
+            cp, self._compile_pool = self._compile_pool, None
             self._closed = True
             ct = self._compact_thread
-        if ex is not None:
-            ex.shutdown(wait=True)
+        for pool in (ex, cp):
+            if pool is not None:
+                pool.shutdown(wait=True)
         if ct is not None and ct.is_alive():
             ct.join()
         with self._mut_lock:
